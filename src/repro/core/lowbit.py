@@ -159,10 +159,10 @@ def _packed_a2a_local(g: jax.Array, dp_axes: Axes, num_workers: int, *,
     plane = K.to_plane(g_eff.reshape(-1))
     words = K.pack_signs(plane, interpret=interpret)      # (R, 128) u32
     r = words.shape[0]
-    pad_r = (-r) % w
+    rw = K.shard_rows(r, w)
+    pad_r = rw * w - r
     if pad_r:
         words = jnp.pad(words, ((0, pad_r), (0, 0)))
-    rw = (r + pad_r) // w
     # "write-side materialization": route worker payloads to the owning
     # aggregator for each element range.
     routed = jax.lax.all_to_all(words.reshape(w, rw, K.LANE), dp_axes,
@@ -185,6 +185,26 @@ def _packed_a2a_local(g: jax.Array, dp_axes: Axes, num_workers: int, *,
     return u, _ef_update(g_eff, ef)
 
 
+def fully_manual(fn, in_specs=P(), out_specs=P()):
+    """``fn`` under a shard_map manual over every mesh axis still automatic.
+
+    Mosaic kernels cannot be partitioned automatically, so every Pallas
+    call in a train step runs where no mesh axis is left to the
+    partitioner.  The train step's shard_map is manual over the DP axes
+    only; this adds the remaining ones (``'model'``) for the span of
+    ``fn``.  Specs default to replicated, so a leaf sharded over an auto
+    axis is gathered first — the semantics of the un-sharded leaf.
+    Outside any mesh (host-local sessions, vmapped virtual workers)
+    ``fn`` is returned as is.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    rest = frozenset(a for a in mesh.axis_names if a not in mesh.manual_axes)
+    if not rest:
+        return fn
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=rest, check_vma=False)
+
+
 def lowbit_packed_a2a(g: jax.Array, dp_axes: Axes, num_workers: int, *,
                       model_spec: P | None = None, ternary: bool = False,
                       gate_phase: int = 0, ef: jax.Array | None = None,
@@ -192,39 +212,28 @@ def lowbit_packed_a2a(g: jax.Array, dp_axes: Axes, num_workers: int, *,
                       kernels: KF.KernelSet | None = None):
     """Controller-schedule aggregation.
 
-    If the leaf is sharded over auto (tensor-parallel) mesh axes,
-    ``model_spec`` must give its PartitionSpec; an inner ``shard_map`` makes
-    the shard fully local so the Pallas datapath can run on it.
-    ``gate_mask`` (fully local payloads only) overrides the flat-index
-    ternary gate — see :func:`_packed_a2a_local`.  ``kernels`` routes the
-    chain to the codec's fused kernel set when present.
+    The datapath runs under :func:`fully_manual`.  If the leaf is sharded
+    over auto (tensor-parallel) mesh axes, ``model_spec`` must give its
+    PartitionSpec, and each shard votes on its own slice.  ``gate_mask``
+    (fully local payloads only) overrides the flat-index ternary gate —
+    see :func:`_packed_a2a_local`.  ``kernels`` routes the chain to the
+    codec's fused kernel set when present.
     """
     kwargs = dict(ternary=ternary, gate_phase=gate_phase, interpret=interpret,
-                  kernels=kernels)
-
-    if model_spec is None or all(a is None for a in model_spec):
-        return _packed_a2a_local(g, dp_axes, num_workers, ef=ef,
-                                 gate_mask=gate_mask, **kwargs)
-    assert gate_mask is None, "gate_mask requires a fully local payload"
-
-    manual = frozenset(a for axes in model_spec if axes is not None
-                       for a in ((axes,) if isinstance(axes, str) else axes))
+                  kernels=kernels, gate_mask=gate_mask)
+    spec = P() if model_spec is None else model_spec
+    assert gate_mask is None or all(a is None for a in spec), \
+        "gate_mask requires a fully local payload"
 
     if ef is None:
         def inner(gl):
             u, _ = _packed_a2a_local(gl, dp_axes, num_workers, ef=None, **kwargs)
             return u
-        u = jax.shard_map(inner, in_specs=model_spec, out_specs=model_spec,
-                          axis_names=manual, check_vma=False)(g)
-        return u, None
+        return fully_manual(inner, spec, spec)(g), None
 
     def inner_ef(gl, efl):
         return _packed_a2a_local(gl, dp_axes, num_workers, ef=efl, **kwargs)
-    u, new_ef = jax.shard_map(
-        inner_ef, in_specs=(model_spec, model_spec),
-        out_specs=(model_spec, model_spec),
-        axis_names=manual, check_vma=False)(g, ef)
-    return u, new_ef
+    return fully_manual(inner_ef, (spec, spec), (spec, spec))(g, ef)
 
 
 # ---------------------------------------------------------------------------

@@ -32,8 +32,12 @@ class MarkovLM:
         rng = np.random.RandomState(seed)
         k = min(topk, vocab)
         self.vocab = vocab
-        # sparse transition structure: each token has k successors
-        self.succ = np.argsort(rng.rand(vocab, vocab), axis=1)[:, :k]
+        # sparse transition structure: each token has k distinct
+        # successors, drawn in O(vocab * k) memory (a dense vocab x vocab
+        # draw is ~185 GB at a 152k vocab): sorted draws from
+        # [0, vocab - k] plus 0..k-1 are strictly increasing
+        draws = np.sort(rng.randint(0, vocab - k + 1, (vocab, k)), axis=1)
+        self.succ = draws + np.arange(k)
         logits = rng.gumbel(size=(vocab, k)) / concentration
         p = np.exp(logits - logits.max(axis=1, keepdims=True))
         self.probs = p / p.sum(axis=1, keepdims=True)

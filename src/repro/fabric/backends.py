@@ -28,7 +28,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from ..core.lowbit import (LeafPolicy, _ef_inject, _ef_update,
-                           fp32_allreduce, lowbit_packed_a2a,
+                           fp32_allreduce, fully_manual, lowbit_packed_a2a,
                            lowbit_vote_psum, sign_of_mean)
 from ..core.modes import Schedule, wire_schedule
 from .codecs import get_codec, resolve_leaf_gate_mask, ring_wire_bytes
@@ -46,6 +46,17 @@ def _codec_kernels(ctx: AggregationContext, codec):
         return None
     hook = getattr(codec, "pallas_kernels", None)
     return None if hook is None else hook()
+
+
+def _mean_kernel_path(ctx: AggregationContext, ks, flat):
+    """A mean codec's kernels around the psum, on a flat payload.  The
+    kernels run under :func:`fully_manual` (Mosaic cannot be partitioned
+    over the step's auto axes)."""
+    def run(x):
+        enc = ks.encode_flat(x, interpret=ctx.interpret)
+        return ks.decode_apply(fp32_allreduce(enc, ctx.dp_axes),
+                               interpret=ctx.interpret)
+    return fully_manual(run)(flat)
 
 
 @register_schedule(Schedule.PSUM, "fp32")
@@ -68,10 +79,7 @@ class Fp32AllreduceBackend:
         if ks is not None and ks.means:
             # fused encode kernel on the flat payload (bit-identical to
             # codec.encode — the KernelSet contract), decode on the mean
-            flat = g.reshape(-1)
-            enc = ks.encode_flat(flat, interpret=ctx.interpret)
-            u = fp32_allreduce(enc.reshape(g.shape), ctx.dp_axes)
-            u = ks.decode_apply(u.reshape(-1), interpret=ctx.interpret)
+            u = _mean_kernel_path(ctx, ks, g.reshape(-1))
             return codec.decode(ctx, u.reshape(g.shape)), ef
         u = codec.decode(ctx, fp32_allreduce(codec.encode(ctx, g),
                                              ctx.dp_axes))
@@ -81,10 +89,7 @@ class Fp32AllreduceBackend:
                        gate=None):
         ks = _codec_kernels(ctx, codec)
         if ks is not None and ks.means:
-            enc = ks.encode_flat(flat, interpret=ctx.interpret)
-            u = ks.decode_apply(fp32_allreduce(enc, ctx.dp_axes),
-                                interpret=ctx.interpret)
-            return codec.decode(ctx, u)
+            return codec.decode(ctx, _mean_kernel_path(ctx, ks, flat))
         return codec.decode(ctx, fp32_allreduce(codec.encode(ctx, flat),
                                                 ctx.dp_axes))
 

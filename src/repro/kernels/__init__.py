@@ -12,15 +12,16 @@ codec quantizers.
 from . import ref
 from .ops import (LANE, PACK, apply_sign_update, from_plane,
                   gate_words_from_mask, interpret_default, majority_decode,
-                  pack_signs, padded_len, popcount_stack, ternary_gate_words,
-                  to_plane, unpack_ternary)
+                  pack_signs, padded_len, popcount_stack, shard_rows,
+                  ternary_gate_words, to_plane, unpack_ternary)
 from .fused import (Int4KernelSet, KernelSet, TopKKernelSet, VoteKernelSet,
                     fused_packed_vote, vote_kernel_set)
 
 __all__ = [
     "ref", "LANE", "PACK", "apply_sign_update", "from_plane",
     "gate_words_from_mask", "interpret_default", "majority_decode",
-    "pack_signs", "padded_len", "popcount_stack", "ternary_gate_words",
+    "pack_signs", "padded_len", "popcount_stack", "shard_rows",
+    "ternary_gate_words",
     "to_plane", "unpack_ternary",
     "KernelSet", "VoteKernelSet", "Int4KernelSet", "TopKKernelSet",
     "fused_packed_vote", "vote_kernel_set",

@@ -20,33 +20,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .ref import LANE, PACK
-from .sign_pack import _pick_word_block
+from .sign_pack import pick_word_block, unpack_rows
 
 
-def _unpack_ternary_kernel(sign_ref, mask_ref, out_ref, *,
-                           words_per_block: int, out_dtype):
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK, LANE), 0)
-    for r in range(words_per_block):
-        sw = jnp.broadcast_to(sign_ref[r:r + 1, :], (PACK, LANE))
-        mw = jnp.broadcast_to(mask_ref[r:r + 1, :], (PACK, LANE))
-        s = ((sw >> shifts) & jnp.uint32(1)).astype(jnp.int32)
-        m = ((mw >> shifts) & jnp.uint32(1)).astype(jnp.int32)
-        u = (2 * s - 1) * m
-        out_ref[r * PACK:(r + 1) * PACK, :] = u.astype(out_dtype)
+def decode_ternary(sign_words: jax.Array, mask_words: jax.Array) -> jax.Array:
+    """(RB, LANE) ternary packed pair -> (32 RB, LANE) int32 in {-1, 0, +1}."""
+    return (2 * unpack_rows(sign_words) - 1) * unpack_rows(mask_words)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "interpret", "block_words"))
+def _unpack_ternary_kernel(sign_ref, mask_ref, out_ref):
+    u = decode_ternary(sign_ref[...], mask_ref[...])
+    out_ref[...] = u.astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
 def unpack_ternary(sign_words: jax.Array, mask_words: jax.Array, *,
-                   dtype=jnp.float32, interpret: bool = False,
-                   block_words: int | None = None) -> jax.Array:
+                   dtype=jnp.float32, interpret: bool = False) -> jax.Array:
     """Ternary packed pair (R, LANE) -> value plane (32 R, LANE) of {-1,0,+1}."""
     r, lane = sign_words.shape
     assert lane == LANE and mask_words.shape == (r, lane)
-    wb = block_words or _pick_word_block(r, max_words=8)
+    wb = pick_word_block(r, max_words=8)
     grid = (r // wb,)
     return pl.pallas_call(
-        functools.partial(_unpack_ternary_kernel, words_per_block=wb,
-                          out_dtype=dtype),
+        _unpack_ternary_kernel,
         out_shape=jax.ShapeDtypeStruct((r * PACK, LANE), dtype),
         grid=grid,
         in_specs=[pl.BlockSpec((wb, LANE), lambda i: (i, 0)),
@@ -57,35 +53,27 @@ def unpack_ternary(sign_words: jax.Array, mask_words: jax.Array, *,
 
 
 def _apply_sign_update_kernel(param_ref, sign_ref, mask_ref, scale_ref,
-                              out_ref, *, words_per_block: int):
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK, LANE), 0)
-    scale = scale_ref[0, 0]
-    for r in range(words_per_block):
-        sw = jnp.broadcast_to(sign_ref[r:r + 1, :], (PACK, LANE))
-        mw = jnp.broadcast_to(mask_ref[r:r + 1, :], (PACK, LANE))
-        s = ((sw >> shifts) & jnp.uint32(1)).astype(jnp.float32)
-        m = ((mw >> shifts) & jnp.uint32(1)).astype(jnp.float32)
-        u = (2.0 * s - 1.0) * m
-        p = param_ref[r * PACK:(r + 1) * PACK, :].astype(jnp.float32)
-        out_ref[r * PACK:(r + 1) * PACK, :] = (p - scale * u).astype(out_ref.dtype)
+                              out_ref):
+    u = decode_ternary(sign_ref[...], mask_ref[...]).astype(jnp.float32)
+    p = param_ref[...].astype(jnp.float32)
+    out_ref[...] = (p - scale_ref[0, 0] * u).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_words"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def apply_sign_update(param_plane: jax.Array, sign_words: jax.Array,
                       mask_words: jax.Array, scale: jax.Array, *,
-                      interpret: bool = False,
-                      block_words: int | None = None) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Fused ``param - scale * decode(sign, mask)`` over a value plane."""
     m, lane = param_plane.shape
     assert lane == LANE and m % PACK == 0
     num_words = m // PACK
     assert sign_words.shape == (num_words, LANE)
     assert mask_words.shape == (num_words, LANE)
-    wb = block_words or _pick_word_block(num_words, max_words=8)
+    wb = pick_word_block(num_words, max_words=8)
     grid = (num_words // wb,)
     scale_arr = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     return pl.pallas_call(
-        functools.partial(_apply_sign_update_kernel, words_per_block=wb),
+        _apply_sign_update_kernel,
         out_shape=jax.ShapeDtypeStruct((m, LANE), param_plane.dtype),
         grid=grid,
         in_specs=[pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0)),

@@ -44,7 +44,9 @@ from jax.experimental import pallas as pl
 
 from . import ref
 from .ref import LANE, PACK
-from .sign_pack import _pick_word_block
+from .apply_update import decode_ternary
+from .popcount_majority import count_votes, decode_margin
+from .sign_pack import pack_rows, pick_word_block, positive
 from .ops import _mode, pack_signs, unpack_ternary
 
 
@@ -93,66 +95,45 @@ def shard_gate_words(dp_axes, rows_per_shard: int, *, ternary: bool,
 # fused kernel bodies
 # ---------------------------------------------------------------------------
 
-def _encode_pack_ef_kernel(g_ref, e_ref, words_ref, geff_ref, *,
-                           words_per_block: int):
+def _encode_pack_ef_kernel(g_ref, e_ref, words_ref, geff_ref):
     """EF inject + sign pack fused: the g_eff = g + e plane is packed the
     moment it is formed, so the unfused path's inject-pass write/re-read
     of g_eff before packing never happens."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK, LANE), 0)
-    for r in range(words_per_block):
-        x = g_ref[r * PACK:(r + 1) * PACK, :] + e_ref[r * PACK:(r + 1) * PACK, :]
-        geff_ref[r * PACK:(r + 1) * PACK, :] = x
-        bits = (x > 0).astype(jnp.uint32)
-        words_ref[r:r + 1, :] = jnp.sum(bits << shifts, axis=0,
-                                        keepdims=True).astype(jnp.uint32)
+    x = g_ref[...] + e_ref[...]
+    geff_ref[...] = x
+    words_ref[...] = pack_rows(positive(x))
 
 
 def _vote_combine_kernel(routed_ref, gate_ref, sign_ref, mask_ref, *,
-                         num_workers: int, words_per_block: int):
+                         num_workers: int):
     """PopCount + majority/ternary decode in one kernel: the (M, LANE)
-    int32 counts plane lives only in registers."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK, LANE), 0)
-    for r in range(words_per_block):
-        acc = jnp.zeros((PACK, LANE), jnp.int32)
-        for w in range(num_workers):
-            word = routed_ref[w, r:r + 1, :]                     # (1, LANE)
-            bits = (jnp.broadcast_to(word, (PACK, LANE)) >> shifts) & jnp.uint32(1)
-            acc = acc + bits.astype(jnp.int32)
-        a = 2 * acc - num_workers                                 # vote margin
-        sign_word = jnp.sum((a > 0).astype(jnp.uint32) << shifts,
-                            axis=0, keepdims=True)
-        mask_word = jnp.sum((a != 0).astype(jnp.uint32) << shifts,
-                            axis=0, keepdims=True)
-        gate = gate_ref[r:r + 1, :]
-        sign_ref[r:r + 1, :] = sign_word.astype(jnp.uint32)
-        mask_ref[r:r + 1, :] = (mask_word & gate).astype(jnp.uint32)
+    int32 counts plane lives only in VMEM."""
+    sign_ref[...], mask_ref[...] = decode_margin(
+        count_votes(routed_ref), gate_ref[...], num_workers)
 
 
-def _vote_pipeline_kernel(stack_ref, gate_ref, out_ref, *, num_workers: int,
-                          words_per_block: int, out_dtype):
+def _vote_pipeline_kernel(stack_ref, gate_ref, out_ref, *, num_workers: int):
     """The whole local vote datapath — encode, popcount, majority, ternary
     gate, decode — as ONE kernel over stacked (W, M, LANE) value planes.
     No packed words, counts, or ternary pair ever reach HBM; counting
     (v > 0) directly is bit-identical to packing the sign bits first."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK, LANE), 0)
-    for r in range(words_per_block):
-        counts = jnp.zeros((PACK, LANE), jnp.int32)
-        for w in range(num_workers):
-            rows = stack_ref[w, r * PACK:(r + 1) * PACK, :]
-            counts = counts + (rows > 0).astype(jnp.int32)
-        a = 2 * counts - num_workers
-        gate = jnp.broadcast_to(gate_ref[r:r + 1, :], (PACK, LANE))
-        keep = ((gate >> shifts) & jnp.uint32(1)).astype(jnp.int32)
-        s = (a > 0).astype(jnp.int32)
-        m = (a != 0).astype(jnp.int32) * keep
-        out_ref[r * PACK:(r + 1) * PACK, :] = ((2 * s - 1) * m).astype(out_dtype)
+    def add(w, acc):
+        return acc + positive(stack_ref[w])
+    counts = jax.lax.fori_loop(0, stack_ref.shape[0], add,
+                               jnp.zeros(out_ref.shape, jnp.int32))
+    sign, mask = decode_margin(counts, gate_ref[...], num_workers)
+    out_ref[...] = decode_ternary(sign, mask).astype(out_ref.dtype)
 
 
 def _ef_residual_kernel(x_ref, beta_ref, out_ref):
-    """EF-signSGD residual e' = x - beta * sgn(x) (beta precomputed)."""
+    """EF-signSGD residual e' = x - beta * sgn(x) (beta precomputed).
+
+    Scalar operands are float32 (Mosaic extracts no narrower scalar) and
+    so is the arithmetic: beta * sgn(x) is exact, so the one rounding
+    back to the plane's dtype is the reference's own."""
     beta = beta_ref[0, 0]
-    x = x_ref[...]
-    out_ref[...] = x - beta * jnp.sign(x)
+    x = x_ref[...].astype(jnp.float32)
+    out_ref[...] = (x - beta * jnp.sign(x)).astype(out_ref.dtype)
 
 
 def _int4_quant_kernel(x_ref, out_ref, acc_ref, *, levels: float):
@@ -186,29 +167,23 @@ def _threshold_mask_kernel(x_ref, t_ref, out_ref):
     """Magnitude sparsify: keep x where |x| >= t (t = k-th magnitude)."""
     t = t_ref[0, 0]
     x = x_ref[...]
-    out_ref[...] = jnp.where(jnp.abs(x) >= t, x, jnp.zeros((), x.dtype))
+    keep = jnp.abs(x.astype(jnp.float32)) >= t
+    out_ref[...] = jnp.where(keep, x, jnp.zeros((), x.dtype))
 
 
 # ---------------------------------------------------------------------------
 # jit'd entry points (same 3-way interpret dispatch as kernels.ops)
 # ---------------------------------------------------------------------------
 
-def _vote_stack_block(num_words: int, num_workers: int) -> int:
-    """Word-block size for kernels holding W stacked planes in VMEM:
-    cap the resident block near 2 MiB (w * wb * TILE * 4 bytes)."""
-    cap = max(1, min(8, 128 // max(1, num_workers)))
-    return _pick_word_block(num_words, max_words=cap)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _encode_pack_ef_call(g_plane, e_plane, *, interpret: bool):
     m, lane = g_plane.shape
     num_words = m // PACK
-    wb = _pick_word_block(num_words, max_words=8)
+    wb = pick_word_block(num_words, max_words=8)
     out_shape = (jax.ShapeDtypeStruct((num_words, LANE), jnp.uint32),
                  jax.ShapeDtypeStruct((m, LANE), g_plane.dtype))
     return pl.pallas_call(
-        functools.partial(_encode_pack_ef_kernel, words_per_block=wb),
+        _encode_pack_ef_kernel,
         out_shape=out_shape,
         grid=(num_words // wb,),
         in_specs=[pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0)),
@@ -232,12 +207,11 @@ def encode_pack_ef(g_plane: jax.Array, e_plane: jax.Array, *,
 def _vote_combine_call(routed, gate_words, *, num_workers: int,
                        interpret: bool):
     w, r, lane = routed.shape
-    wb = _pick_word_block(r, max_words=8)
+    wb = pick_word_block(r, max_words=8)
     out_shape = (jax.ShapeDtypeStruct((r, LANE), jnp.uint32),
                  jax.ShapeDtypeStruct((r, LANE), jnp.uint32))
     return pl.pallas_call(
-        functools.partial(_vote_combine_kernel, num_workers=w,
-                          words_per_block=wb),
+        functools.partial(_vote_combine_kernel, num_workers=w),
         out_shape=out_shape,
         grid=(r // wb,),
         in_specs=[pl.BlockSpec((w, wb, LANE), lambda i: (0, i, 0)),
@@ -268,10 +242,9 @@ def _vote_pipeline_call(stack, gate_words, *, num_workers: int, dtype,
                         interpret: bool):
     w, m, lane = stack.shape
     num_words = m // PACK
-    wb = _vote_stack_block(num_words, w)
+    wb = pick_word_block(num_words, max_words=8)
     return pl.pallas_call(
-        functools.partial(_vote_pipeline_kernel, num_workers=w,
-                          words_per_block=wb, out_dtype=dtype),
+        functools.partial(_vote_pipeline_kernel, num_workers=w),
         out_shape=jax.ShapeDtypeStruct((m, LANE), dtype),
         grid=(num_words // wb,),
         in_specs=[pl.BlockSpec((w, wb * PACK, LANE), lambda i: (0, i, 0)),
@@ -298,10 +271,15 @@ def vote_pipeline(stack: jax.Array, gate_words: jax.Array, *,
                                dtype=dtype, interpret=(m == "interp"))
 
 
+def _scalar_operand(x, dtype) -> jax.Array:
+    """A scalar rounded to the plane's dtype, carried as (1, 1) float32."""
+    return jnp.asarray(x, dtype).astype(jnp.float32).reshape(1, 1)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _ef_residual_call(plane, beta, *, interpret: bool):
     m, lane = plane.shape
-    rb = _pick_word_block(m // PACK, max_words=8) * PACK
+    rb = pick_word_block(m // PACK, max_words=8) * PACK
     return pl.pallas_call(
         _ef_residual_kernel,
         out_shape=jax.ShapeDtypeStruct((m, LANE), plane.dtype),
@@ -310,7 +288,7 @@ def _ef_residual_call(plane, beta, *, interpret: bool):
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rb, LANE), lambda i: (i, 0)),
         interpret=interpret,
-    )(plane, jnp.asarray(beta, plane.dtype).reshape(1, 1))
+    )(plane, _scalar_operand(beta, plane.dtype))
 
 
 def ef_residual_plane(plane: jax.Array, beta, *,
@@ -325,7 +303,7 @@ def ef_residual_plane(plane: jax.Array, beta, *,
 @functools.partial(jax.jit, static_argnames=("levels", "interpret"))
 def _int4_quant_call(plane, *, levels: float, interpret: bool):
     m, lane = plane.shape
-    rb = _pick_word_block(m // PACK, max_words=8) * PACK
+    rb = pick_word_block(m // PACK, max_words=8) * PACK
     nblocks = m // rb
     return pl.pallas_call(
         functools.partial(_int4_quant_kernel, levels=levels),
@@ -355,7 +333,7 @@ def int4_quant_plane(plane: jax.Array, *, levels: float = 7.0,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _threshold_mask_call(plane, thresh, *, interpret: bool):
     m, lane = plane.shape
-    rb = _pick_word_block(m // PACK, max_words=8) * PACK
+    rb = pick_word_block(m // PACK, max_words=8) * PACK
     return pl.pallas_call(
         _threshold_mask_kernel,
         out_shape=jax.ShapeDtypeStruct((m, LANE), plane.dtype),
@@ -364,7 +342,7 @@ def _threshold_mask_call(plane, thresh, *, interpret: bool):
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rb, LANE), lambda i: (i, 0)),
         interpret=interpret,
-    )(plane, jnp.asarray(thresh, plane.dtype).reshape(1, 1))
+    )(plane, _scalar_operand(thresh, plane.dtype))
 
 
 def threshold_mask_plane(plane: jax.Array, thresh, *,
@@ -435,10 +413,10 @@ def fused_packed_vote(g: jax.Array, dp_axes, num_workers: int, *,
                                            interpret=interpret)
         g_eff = ref.from_plane(geff_plane, n).reshape(g.shape)
     r = words.shape[0]
-    pad_r = (-r) % w
+    rw = ref.shard_rows(r, w)
+    pad_r = rw * w - r
     if pad_r:
         words = jnp.pad(words, ((0, pad_r), (0, 0)))
-    rw = (r + pad_r) // w
     routed = jax.lax.all_to_all(words.reshape(w, rw, LANE), dp_axes,
                                 split_axis=0, concat_axis=0, tiled=False)
     gate = shard_gate_words(dp_axes, rw, ternary=ternary,

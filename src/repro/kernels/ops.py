@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .ref import LANE, PACK, padded_len, to_plane, from_plane
+from .ref import LANE, PACK, padded_len, shard_rows, to_plane, from_plane
 from .sign_pack import sign_pack as _sign_pack_pallas
 from .popcount_majority import (popcount_stack as _popcount_pallas,
                                 majority_decode as _majority_pallas)
@@ -28,7 +28,7 @@ __all__ = [
     "interpret_default", "pack_signs", "popcount_stack", "majority_decode",
     "unpack_ternary", "apply_sign_update", "ternary_gate_words",
     "gate_words_from_mask", "to_plane", "from_plane", "padded_len",
-    "LANE", "PACK",
+    "shard_rows", "LANE", "PACK",
 ]
 
 

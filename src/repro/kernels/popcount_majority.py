@@ -15,9 +15,10 @@ G-Binary (gate = all ones; zeros only on vote ties) and G-Ternary
 
 TPU mapping notes: counts are int32, so any worker-group width W fits
 (the int8 accumulator the datapath originally used capped groups at
-W <= 127 and silently wrapped beyond); all tiles are (8k, 128) VREG-aligned;
-the word <-> value fan-out of 32 is expressed as a sublane reduction /
-broadcast so no Mosaic-unfriendly reshape crosses the lane dim.
+W <= 127 and silently wrapped beyond); word-plane blocks are (8k, 128)
+VREG-aligned (see :mod:`.sign_pack`); the word <-> value fan-out of 32 is
+a sublane reduction / broadcast (:func:`~.sign_pack.pack_rows` /
+:func:`~.sign_pack.unpack_rows`), so no reshape crosses the lane dim.
 """
 from __future__ import annotations
 
@@ -28,36 +29,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .ref import LANE, PACK
-from .sign_pack import _pick_word_block
+from .sign_pack import pack_rows, pick_word_block, unpack_rows
 
 
 # ---------------------------------------------------------------------------
 # popcount across workers
 # ---------------------------------------------------------------------------
 
-def _popcount_stack_kernel(packed_ref, out_ref, *, num_workers: int,
-                           words_per_block: int):
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK, LANE), 0)
-    for r in range(words_per_block):
-        acc = jnp.zeros((PACK, LANE), jnp.int32)
-        for w in range(num_workers):
-            word = packed_ref[w, r:r + 1, :]                     # (1, LANE)
-            bits = (jnp.broadcast_to(word, (PACK, LANE)) >> shifts) & jnp.uint32(1)
-            acc = acc + bits.astype(jnp.int32)
-        out_ref[r * PACK:(r + 1) * PACK, :] = acc
+def count_votes(packed_ref) -> jax.Array:
+    """(W, RB, LANE) packed sign words -> (32 RB, LANE) int32 vote counts,
+    one worker's word block at a time."""
+    def add(w, acc):
+        return acc + unpack_rows(packed_ref[w])
+    _, rb, lane = packed_ref.shape
+    return jax.lax.fori_loop(0, packed_ref.shape[0], add,
+                             jnp.zeros((rb * PACK, lane), jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_words"))
-def popcount_stack(packed: jax.Array, *, interpret: bool = False,
-                   block_words: int | None = None) -> jax.Array:
+def _popcount_stack_kernel(packed_ref, out_ref):
+    out_ref[...] = count_votes(packed_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def popcount_stack(packed: jax.Array, *, interpret: bool = False) -> jax.Array:
     """(W, R, LANE) uint32 packed sign words -> (32 R, LANE) int32 vote counts."""
     w, r, lane = packed.shape
     assert lane == LANE
-    wb = block_words or _pick_word_block(r, max_words=8)
+    wb = pick_word_block(r, max_words=8)
     grid = (r // wb,)
     return pl.pallas_call(
-        functools.partial(_popcount_stack_kernel, num_workers=w,
-                          words_per_block=wb),
+        _popcount_stack_kernel,
         out_shape=jax.ShapeDtypeStruct((r * PACK, LANE), jnp.int32),
         grid=grid,
         in_specs=[pl.BlockSpec((w, wb, LANE), lambda i: (0, i, 0))],
@@ -70,26 +71,23 @@ def popcount_stack(packed: jax.Array, *, interpret: bool = False,
 # majority / ternary decode of vote counts
 # ---------------------------------------------------------------------------
 
+def decode_margin(counts: jax.Array, gate: jax.Array, num_workers: int):
+    """(32 RB, LANE) int32 counts + (RB, LANE) gate -> (sign, mask) words."""
+    a = 2 * counts - num_workers                                    # vote margin
+    sign = pack_rows((a > 0).astype(jnp.int32))
+    mask = pack_rows((a != 0).astype(jnp.int32))
+    return sign, mask & gate
+
+
 def _majority_decode_kernel(counts_ref, gate_ref, sign_ref, mask_ref, *,
-                            num_workers: int, words_per_block: int):
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (PACK, LANE), 0)
-    for r in range(words_per_block):
-        c = counts_ref[r * PACK:(r + 1) * PACK, :].astype(jnp.int32)  # (32, LANE)
-        a = 2 * c - num_workers                                        # vote margin
-        sign_bits = (a > 0).astype(jnp.uint32)
-        nz_bits = (a != 0).astype(jnp.uint32)
-        sign_word = jnp.sum(sign_bits << shifts, axis=0, keepdims=True)
-        mask_word = jnp.sum(nz_bits << shifts, axis=0, keepdims=True)
-        gate = gate_ref[r:r + 1, :]
-        sign_ref[r:r + 1, :] = sign_word.astype(jnp.uint32)
-        mask_ref[r:r + 1, :] = (mask_word & gate).astype(jnp.uint32)
+                            num_workers: int):
+    sign_ref[...], mask_ref[...] = decode_margin(
+        counts_ref[...].astype(jnp.int32), gate_ref[...], num_workers)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_workers", "interpret", "block_words"))
+@functools.partial(jax.jit, static_argnames=("num_workers", "interpret"))
 def majority_decode(counts: jax.Array, gate_words: jax.Array, *,
-                    num_workers: int, interpret: bool = False,
-                    block_words: int | None = None):
+                    num_workers: int, interpret: bool = False):
     """Vote counts (M, LANE) + packed gate (M//32, LANE) -> ternary packed pair.
 
     Returns (sign_words, mask_words), each (M // 32, LANE) uint32.
@@ -99,13 +97,12 @@ def majority_decode(counts: jax.Array, gate_words: jax.Array, *,
     assert lane == LANE and m % PACK == 0
     num_words = m // PACK
     assert gate_words.shape == (num_words, LANE)
-    wb = block_words or _pick_word_block(num_words, max_words=8)
+    wb = pick_word_block(num_words, max_words=8)
     grid = (num_words // wb,)
     out_shape = (jax.ShapeDtypeStruct((num_words, LANE), jnp.uint32),
                  jax.ShapeDtypeStruct((num_words, LANE), jnp.uint32))
     return pl.pallas_call(
-        functools.partial(_majority_decode_kernel, num_workers=num_workers,
-                          words_per_block=wb),
+        functools.partial(_majority_decode_kernel, num_workers=num_workers),
         out_shape=out_shape,
         grid=grid,
         in_specs=[pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0)),

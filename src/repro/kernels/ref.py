@@ -4,9 +4,12 @@ These mirror, bit-for-bit, the packed payload layout used by the Pallas
 kernels.  All kernels operate on the *canonical bucket layout*:
 
     flat gradient bucket of N elements
-      -> zero-padded to a multiple of LANE * 32
-      -> reshaped to (M, LANE) with M a multiple of 32     ("value plane")
+      -> zero-padded to a multiple of LANE * 32 * 8
+      -> reshaped to (M, LANE) with M a multiple of 256    ("value plane")
       -> sign words of shape (M // 32, LANE), uint32        ("word plane")
+
+The word plane therefore has a multiple of 8 rows: whole (8, 128) VREG
+tiles, which is what Mosaic needs of every word-plane block.
 
 Bit ``b`` of word ``w[r, l]`` holds the sign of value ``v[32 * r + b, l]``
 (1 = strictly positive, 0 = non-positive).  This is the TPU adaptation of
@@ -34,12 +37,21 @@ import numpy as np
 
 LANE = 128          # TPU vector lane count; canonical last dim
 PACK = 32           # sign bits per uint32 word
+SUBLANE = 8         # word rows per (8, 128) VREG tile
 TILE = LANE * PACK  # elements covered by one word row
 
 
 def padded_len(n: int) -> int:
-    """Canonical padded length for an N-element bucket."""
-    return ((n + TILE - 1) // TILE) * TILE
+    """Canonical padded length for an N-element bucket: whole word tiles."""
+    step = TILE * SUBLANE
+    return ((n + step - 1) // step) * step
+
+
+def shard_rows(num_words: int, num_workers: int) -> int:
+    """Word rows each of W owners receives from the packed all_to_all:
+    the word plane right-padded so every shard is whole tiles."""
+    step = SUBLANE * num_workers
+    return (num_words + step - 1) // step * SUBLANE
 
 
 def to_plane(flat: jax.Array) -> jax.Array:
@@ -131,7 +143,7 @@ def ternary_gate_words(num_rows: int, phase: int = 0) -> jax.Array:
     """
     assert num_rows % PACK == 0
     keep = ((np.arange(num_rows * LANE, dtype=np.int64) + phase) % 3) != 2
-    return gate_words_from_mask(keep)
+    return gate_words_from_mask(keep)[:num_rows // PACK]
 
 
 def gate_words_from_mask(keep: np.ndarray,

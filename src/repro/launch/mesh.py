@@ -18,14 +18,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     links — exactly the boundary where NEURON-Fabric's low-bit gradient
     aggregation buys the most (DESIGN.md §4).
     """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return make_mesh((2, 16, 16) if multi_pod else (16, 16))
 
 
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU tests (requires forced host device count)."""
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+def make_mesh(shape):
+    """Mesh of auto axes: ``('data', 'model')`` for two dims,
+    ``('pod', 'data', 'model')`` for three."""
+    axes = ("pod", "data", "model")[-len(shape):]
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes_of(mesh) -> tuple:

@@ -19,6 +19,7 @@ same table the dry-run uses); ``--controller`` accepts any name in the
 import argparse
 import logging
 import os
+import pathlib
 
 #: preset names, hardcoded so --help works without importing jax;
 #: validated against plan_presets() at startup
@@ -28,6 +29,51 @@ _PLAN_CHOICES = ["fp32", "gbin_backbone", "gbin_vote", "gbin_packed",
                  "int4_backbone", "topk_backbone",
                  "hier_fp32_gbinary", "hier_fp32_gternary",
                  "hier_fp32_int4", "adaptive"]
+
+#: the checkout root (src/repro/launch/train.py -> three levels up)
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (JAX reads the
+    variable itself, so nothing is set here).  Otherwise the cache is the
+    fixed, gitignored ``.jax_cache/`` of the checkout: the path is part
+    of the cache key, so it never depends on a temp name, pid or time.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_trainer(cfg, mesh, dp_axes, *, plan=None, fabric=None,
+                  global_batch: int, seq_len: int, steps: int,
+                  optimizer: str = "adamw", lr: float = 3e-4, seed: int = 0,
+                  ckpt_dir=None, ckpt_interval: int = 100):
+    """The launcher's Trainer: synthetic LM stream, optimizer, session.
+
+    ``plan`` is a static admission plan; leave it None and pass a
+    ``fabric`` with a controller attached for an adaptive run.
+    """
+    from ..data import SyntheticLMStream
+    from ..fabric import Fabric
+    from ..optim import AdamW, SgdMomentum
+    from ..runtime import Trainer, TrainerConfig
+
+    data = SyntheticLMStream(vocab=cfg.vocab_size, seq_len=seq_len,
+                             batch=global_batch, seed=seed)
+    opt_cls = AdamW if optimizer == "adamw" else SgdMomentum
+    return Trainer(
+        cfg, mesh, opt_cls(peak_lr=lr, total_steps=steps), data, plan=plan,
+        fabric=fabric if fabric is not None else Fabric(mesh, dp_axes),
+        tcfg=TrainerConfig(dp_axes=dp_axes,
+                           checkpoint_interval=ckpt_interval),
+        ckpt_dir=ckpt_dir, seed=seed)
 
 
 def main():
@@ -73,30 +119,19 @@ def main():
                                    f"{args.device_count}").strip()
 
     import jax
-    from jax.sharding import AxisType
 
     from ..configs import get_config
-    from ..data import SyntheticLMStream
     from ..fabric import Fabric
     from ..fabric.control import plan_presets
-    from ..optim import AdamW, SgdMomentum
-    from ..runtime import Trainer, TrainerConfig
+    from .mesh import dp_axes_of, make_mesh
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    use_compile_cache()
 
-    shape = tuple(int(x) for x in args.mesh.split(","))
-    axes = ("pod", "data", "model")[-len(shape):]
-    mesh = jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(shape))
-    dp_axes = tuple(a for a in axes if a != "model")
-
+    mesh = make_mesh(tuple(int(x) for x in args.mesh.split(",")))
+    dp_axes = dp_axes_of(mesh)
     cfg = get_config(args.arch, smoke=args.smoke)
-    data = SyntheticLMStream(vocab=cfg.vocab_size, seq_len=args.seq_len,
-                             batch=args.global_batch, seed=args.seed)
-
-    opt_cls = AdamW if args.optimizer == "adamw" else SgdMomentum
-    optimizer = opt_cls(peak_lr=args.lr, total_steps=args.steps)
 
     plans = plan_presets(error_feedback=args.error_feedback)
     # built-in choices must all resolve; plan_presets may additionally
@@ -139,11 +174,12 @@ def main():
     else:
         plan = plans[args.plan]
 
-    trainer = Trainer(
-        cfg, mesh, optimizer, data, plan=plan, fabric=fabric,
-        tcfg=TrainerConfig(dp_axes=dp_axes,
-                           checkpoint_interval=args.ckpt_interval),
-        ckpt_dir=args.ckpt_dir, seed=args.seed)
+    trainer = build_trainer(
+        cfg, mesh, dp_axes, plan=plan, fabric=fabric,
+        global_batch=args.global_batch, seq_len=args.seq_len,
+        steps=args.steps, optimizer=args.optimizer, lr=args.lr,
+        seed=args.seed, ckpt_dir=args.ckpt_dir,
+        ckpt_interval=args.ckpt_interval)
     history = trainer.run(args.steps)
     last = history[-1]
     print(f"final: step={last['step']} loss={last['loss']:.4f} "

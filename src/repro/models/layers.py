@@ -27,29 +27,14 @@ from .config import ModelConfig, MoEConfig
 
 def shard(x: jax.Array, *entries):
     """with_sharding_constraint that tolerates missing/manual mesh axes."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return x
-    if mesh is None or not mesh.axis_names:
-        return x
-    try:
-        types = dict(zip(mesh.axis_names, mesh.axis_types))
-    except Exception:
-        types = {a: None for a in mesh.axis_names}
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = set(mesh.axis_names) - set(mesh.manual_axes)
 
     def ok(axis) -> bool:
-        if axis is None:
-            return True
         axes = (axis,) if isinstance(axis, str) else tuple(axis)
-        for a in axes:
-            if a not in mesh.axis_names:
-                return False
-            if str(types.get(a)) == "AxisType.Manual" or repr(types.get(a)) == "Manual":
-                return False
-        return True
+        return all(a in auto for a in axes)
 
-    cleaned = tuple(a if ok(a) else None for a in entries)
+    cleaned = tuple(a if a is not None and ok(a) else None for a in entries)
     if all(a is None for a in cleaned):
         return x
     return jax.lax.with_sharding_constraint(x, P(*cleaned))

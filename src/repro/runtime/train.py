@@ -188,6 +188,10 @@ class Trainer:
         ef = self.fabric.init_ef(params, policies)
         self.state = TrainState(params=params, opt=opt, ef=ef,
                                 step=jnp.zeros((), jnp.int32))
+        # placed as the step places its output, so the step compiled for
+        # this state serves every later step too
+        self.state = jax.device_put(
+            self.state, self.compiled_step(plan).state_shardings)
         self._sizes = self.fabric.group_sizes(params)
         return self.state
 
@@ -196,11 +200,17 @@ class Trainer:
             return self.controller.plan
         return self.static_plan or AdmissionPlan.fp32_all()
 
+    def compiled_step(self, plan: AdmissionPlan | None = None,
+                      diagnostics: bool = False) -> CompiledStep:
+        """The session's step for ``plan`` (default: the current plan) —
+        the one :meth:`run` executes.  Needs :meth:`init_state` first."""
+        return self.fabric.step_for(
+            self.cfg, self.optimizer, plan or self._current_plan(),
+            self.state.params, with_diagnostics=diagnostics,
+            loss=self.loss, zero1=self.tcfg.zero1)
+
     def _get_step(self, plan: AdmissionPlan, diagnostics: bool):
-        step = self.fabric.step_for(
-            self.cfg, self.optimizer, plan, self.state.params,
-            with_diagnostics=diagnostics, loss=self.loss,
-            zero1=self.tcfg.zero1)
+        step = self.compiled_step(plan, diagnostics)
         return step, step.batch_sharding
 
     # -- loop -----------------------------------------------------------

@@ -14,13 +14,3 @@ import pytest
 def rng():
     return np.random.RandomState(0)
 
-
-import jax
-import jax.sharding
-
-#: the partial-manual shard_map runtime needs the jax >= 0.7 API surface
-JAX_CAPABLE = (hasattr(jax, "shard_map") and hasattr(jax, "set_mesh")
-               and hasattr(jax.sharding, "AxisType"))
-needs_modern_jax = pytest.mark.skipif(
-    not JAX_CAPABLE,
-    reason="installed jax lacks shard_map/set_mesh/AxisType (needs >= 0.7)")

@@ -593,10 +593,7 @@ def test_topk_codec_sparsifies_and_aggregates(rng):
 def test_extra_codec_trains_through_build_step(rng):
     """Acceptance: the int4 codec trains through Fabric.build_step —
     resolved purely by plan name, fused by default, loss decreases."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        pytest.skip("installed jax lacks AxisType (needs >= 0.7)")
+    from jax.sharding import AxisType
     from repro.models import ModelConfig, init_params
     from repro.optim import SgdMomentum
     from repro.fabric import TrainState

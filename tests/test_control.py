@@ -34,8 +34,6 @@ from repro.fabric.control import (Controller, FP32Controller,
                                   unregister_controller)
 from repro.runtime.fault import FailureInjector, SimulatedFailure
 
-from conftest import needs_modern_jax
-
 COS = {"backbone": {"gbinary": 0.8, "gternary": 0.7},
        "head": {"gbinary": 0.1, "gternary": 0.1}}
 
@@ -630,7 +628,6 @@ def _trainer_bits():
     return mesh, cfg, SyntheticLMStream, SgdMomentum, Trainer, TrainerConfig
 
 
-@needs_modern_jax
 def test_static_controller_bit_identical_to_legacy_plan_path():
     mesh, cfg, Stream, Sgd, Trainer, TrainerConfig = _trainer_bits()
     plan = AdmissionPlan.lowbit_backbone(AggregationMode.G_BINARY,
@@ -657,7 +654,6 @@ def test_static_controller_bit_identical_to_legacy_plan_path():
         np.asarray([h["agg_norm"] for h in h_legacy]))
 
 
-@needs_modern_jax
 def test_custom_registered_controller_drives_trainer():
     """A test-registered controller flips the plan mid-run, selected
     purely by name through attach_controller — no core edits."""
@@ -695,7 +691,6 @@ def test_custom_registered_controller_drives_trainer():
         unregister_controller("toy_flip")
 
 
-@needs_modern_jax
 def test_trainer_controller_state_survives_failure_injector(tmp_path):
     """Satellite regression: SimulatedFailure mid-cooldown; the restored
     run must keep the Supervisor cooldown and the admitted plan."""

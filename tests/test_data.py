@@ -41,6 +41,19 @@ def test_markov_chain_is_learnable():
     assert max(len(v) for v in succ.values()) <= 4
 
 
+def test_markov_chain_builds_at_full_vocab():
+    """qwen3's 151,936-token vocab: successors cost O(vocab * topk) host
+    memory, stay distinct per token, and are deterministic per seed."""
+    import time
+    t0 = time.perf_counter()
+    chain = MarkovLM(vocab=151936, seed=0)
+    assert time.perf_counter() - t0 < 10.0
+    assert chain.succ.shape == (151936, 16)
+    assert chain.succ.min() >= 0 and chain.succ.max() < 151936
+    assert (np.diff(chain.succ, axis=1) > 0).all()
+    np.testing.assert_array_equal(chain.succ, MarkovLM(151936, seed=0).succ)
+
+
 def test_prefetcher_yields_everything():
     it = iter([{"x": i} for i in range(7)])
     out = list(Prefetcher(it, depth=2))

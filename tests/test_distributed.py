@@ -19,8 +19,6 @@ import textwrap
 
 import pytest
 
-from conftest import needs_modern_jax
-
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
@@ -40,7 +38,6 @@ def run_script(body: str, devices: int = 8, timeout: int = 900) -> str:
 
 
 @pytest.mark.slow
-@needs_modern_jax
 def test_aggregation_schedules_match_dense_oracle():
     out = run_script("""
     import functools, jax, jax.numpy as jnp, numpy as np
@@ -78,7 +75,6 @@ def test_aggregation_schedules_match_dense_oracle():
 
 
 @pytest.mark.slow
-@needs_modern_jax
 def test_trainer_failure_recovery_is_deterministic():
     out = run_script("""
     import jax, tempfile, shutil
@@ -121,7 +117,6 @@ def test_trainer_failure_recovery_is_deterministic():
 
 
 @pytest.mark.slow
-@needs_modern_jax
 def test_elastic_restart_across_mesh_shapes():
     out = run_script("""
     import jax, jax.numpy as jnp, numpy as np, tempfile
@@ -161,7 +156,6 @@ def test_elastic_restart_across_mesh_shapes():
 
 
 @pytest.mark.slow
-@needs_modern_jax
 def test_dryrun_entrypoint_full_size_cell(tmp_path):
     """The production dry-run proves (e): lower+compile on the 16x16 mesh."""
     env = dict(os.environ)
@@ -192,7 +186,6 @@ def test_straggler_watchdog():
 
 
 @pytest.mark.slow
-@needs_modern_jax
 def test_grad_accumulation_equivalence():
     """grad_accum=4 reproduces grad_accum=1 (linear FP32 aggregation)."""
     out = run_script("""

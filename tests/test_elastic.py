@@ -241,10 +241,7 @@ def test_restore_across_membership_change_replans_and_keeps_phase(tmp_path):
 def test_fabric_step_cache_keys_include_membership_epoch():
     """Satellite 6 at the session level: re-binding an epoch-bumped view
     must miss the jit cache even at the same worker count."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        pytest.skip("installed jax lacks jax.sharding.AxisType")
+    from jax.sharding import AxisType
     from repro.fabric import Fabric
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)

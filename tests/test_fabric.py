@@ -24,8 +24,6 @@ from repro.fabric import (AggregationContext, Fabric, ScheduleBackend,
                           available_schedules, get_schedule,
                           register_schedule, unregister_schedule)
 
-from conftest import needs_modern_jax
-
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
@@ -345,7 +343,6 @@ def test_custom_schedule_trains_one_step(rng):
 
 
 @pytest.mark.slow
-@needs_modern_jax
 def test_custom_schedule_trains_via_trainer_on_mesh():
     """Full stack: a registered toy schedule drives the Trainer on a real
     (simulated-device) mesh, selected only by its plan name."""

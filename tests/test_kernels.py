@@ -129,5 +129,6 @@ def test_vote_tie_decodes_to_zero():
     stack = jnp.stack([K.pack_signs(p, interpret=True) for p in planes])
     counts = K.popcount_stack(stack, interpret=True)
     sw, mw = K.majority_decode(counts, num_workers=w, interpret=True)
-    u = K.unpack_ternary(sw, mw, interpret=True)
+    u = ref.from_plane(K.unpack_ternary(sw, mw, interpret=True),
+                       grads.shape[1])
     assert np.all(np.asarray(u) == 0.0)

@@ -9,12 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    pytest.skip("installed jax lacks jax.sharding.AxisType (needs >= 0.7)",
-                allow_module_level=True)
+from jax.sharding import AxisType
 
 from repro.core import (AdmissionPlan, AggregationMode, Commander,
                         CusumGuard, Schedule, Supervisor)
