@@ -155,34 +155,40 @@ def _packed_a2a_local(g: jax.Array, dp_axes: Axes, num_workers: int, *,
                                    interpret=interpret, gate_mask=gate_mask)
     w = num_workers
     n = g.size
-    g_eff, ef = _ef_inject(g, ef)
-    plane = K.to_plane(g_eff.reshape(-1))
-    words = K.pack_signs(plane, interpret=interpret)      # (R, 128) u32
-    r = words.shape[0]
-    rw = K.shard_rows(r, w)
-    pad_r = rw * w - r
-    if pad_r:
-        words = jnp.pad(words, ((0, pad_r), (0, 0)))
+    with jax.named_scope("encode"):
+        g_eff, ef = _ef_inject(g, ef)
+        plane = K.to_plane(g_eff.reshape(-1))
+        words = K.pack_signs(plane, interpret=interpret)  # (R, 128) u32
+        r = words.shape[0]
+        rw = K.shard_rows(r, w)
+        pad_r = rw * w - r
+        if pad_r:
+            words = jnp.pad(words, ((0, pad_r), (0, 0)))
     # "write-side materialization": route worker payloads to the owning
     # aggregator for each element range.
-    routed = jax.lax.all_to_all(words.reshape(w, rw, K.LANE), dp_axes,
-                                split_axis=0, concat_axis=0, tiled=False)
+    with jax.named_scope("exchange"):
+        routed = jax.lax.all_to_all(words.reshape(w, rw, K.LANE), dp_axes,
+                                    split_axis=0, concat_axis=0, tiled=False)
     # "controller datapath": PopCount across workers + majority/ternary gate
     # (the gate helper is shared with the fused driver, so both pipelines
     # consume byte-identical zero gates by construction).
-    counts = K.popcount_stack(routed, interpret=interpret)
-    gate = KF.shard_gate_words(dp_axes, rw, ternary=ternary,
-                               gate_phase=gate_phase, gate_mask=gate_mask,
-                               total_rows=r + pad_r)
-    sw, mw = K.majority_decode(counts, num_workers=w, gate_words=gate,
-                               interpret=interpret)
+    with jax.named_scope("vote"):
+        counts = K.popcount_stack(routed, interpret=interpret)
+        gate = KF.shard_gate_words(dp_axes, rw, ternary=ternary,
+                                   gate_phase=gate_phase, gate_mask=gate_mask,
+                                   total_rows=r + pad_r)
+        sw, mw = K.majority_decode(counts, num_workers=w, gate_words=gate,
+                                   interpret=interpret)
     # "read response": packed ternary aggregate gathered back to all workers.
-    sw_all = jax.lax.all_gather(sw, dp_axes, axis=0, tiled=True)[:r]
-    mw_all = jax.lax.all_gather(mw, dp_axes, axis=0, tiled=True)[:r]
-    u_plane = K.unpack_ternary(sw_all, mw_all, dtype=jnp.float32,
-                               interpret=interpret)
-    u = K.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
-    return u, _ef_update(g_eff, ef)
+    with jax.named_scope("exchange"):
+        sw_all = jax.lax.all_gather(sw, dp_axes, axis=0, tiled=True)[:r]
+        mw_all = jax.lax.all_gather(mw, dp_axes, axis=0, tiled=True)[:r]
+    with jax.named_scope("decode"):
+        u_plane = K.unpack_ternary(sw_all, mw_all, dtype=jnp.float32,
+                                   interpret=interpret)
+        u = K.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
+    with jax.named_scope("encode"):      # the residual is the encoder's state
+        return u, _ef_update(g_eff, ef)
 
 
 def fully_manual(fn, in_specs=P(), out_specs=P()):

@@ -249,24 +249,26 @@ def aggregate_tree_bucketed(ctx: AggregationContext, grads: Any,
         # EF rides the fused collective only when both axes can carry it
         threads_ef = getattr(backend, "threads_ef", False) and codec.threads_ef
         flats, g_effs = [], {}
-        for slot in bucket.slots:
-            g = g_leaves[slot.leaf].reshape(-1)
-            e, pol = e_leaves[slot.leaf], p_leaves[slot.leaf]
-            if (threads_ef and pol.error_feedback and e is not None
-                    and e.ndim > 0):
-                g = g + e[0].reshape(-1).astype(g.dtype)
-                g_effs[slot.leaf] = g
-            flats.append(g)
-        flat = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
+        with jax.named_scope("bucket.pack"):
+            for slot in bucket.slots:
+                g = g_leaves[slot.leaf].reshape(-1)
+                e, pol = e_leaves[slot.leaf], p_leaves[slot.leaf]
+                if (threads_ef and pol.error_feedback and e is not None
+                        and e.ndim > 0):
+                    g = g + e[0].reshape(-1).astype(g.dtype)
+                    g_effs[slot.leaf] = g
+                flats.append(g)
+            flat = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
         u_flat = backend.aggregate_flat(ctx, flat, codec,
                                         gate=bucket.gate())
-        for slot in bucket.slots:
-            u = u_flat[slot.offset:slot.offset + slot.size]
-            agg[slot.leaf] = u.reshape(slot.shape)
-            if slot.leaf in g_effs:
-                e = e_leaves[slot.leaf]
-                g_eff = g_effs[slot.leaf].reshape(slot.shape)
-                new_ef[slot.leaf] = _ef_update(g_eff, e[0])[None]
+        with jax.named_scope("bucket.scatter"):
+            for slot in bucket.slots:
+                u = u_flat[slot.offset:slot.offset + slot.size]
+                agg[slot.leaf] = u.reshape(slot.shape)
+                if slot.leaf in g_effs:
+                    e = e_leaves[slot.leaf]
+                    g_eff = g_effs[slot.leaf].reshape(slot.shape)
+                    new_ef[slot.leaf] = _ef_update(g_eff, e[0])[None]
 
     aggregates = jax.tree_util.tree_unflatten(treedef, agg)
     if ef_states is None:
@@ -655,7 +657,14 @@ class Fabric:
         policies = self.resolve(params_like, plan, pspecs=pspecs)
         groups = self.groups(params_like)
         ef_specs = self.ef_specs(policies, pspecs)
-        lf = loss or (lambda p, b: model_loss_fn(p, cfg, b))
+        base_loss = loss or (lambda p, b: model_loss_fn(p, cfg, b))
+
+        def lf(p, b):
+            # forward ops carry ``jvp(model)`` in their HLO op_name, backward
+            # ops (the remat recompute included) ``transpose(jvp(model))``
+            with jax.named_scope("model"):
+                return base_loss(p, b)
+
         use_fused = self.fused if fused is None else fused
         layout = (self.layout_for(params_like, policies)
                   if use_fused else None)
@@ -684,12 +693,13 @@ class Fabric:
                 grads = jax.tree.map(lambda x: x / grad_accum, grads)
             else:
                 lval, grads = jax.value_and_grad(lf)(params, batch)
-            if use_fused:
-                agg, new_ef = aggregate_tree_bucketed(
-                    ctx, grads, policies, ef_states=ef, layout=layout)
-            else:
-                agg, new_ef = aggregate_tree(ctx, grads, policies,
-                                             ef_states=ef)
+            with jax.named_scope("fabric.aggregate"):
+                if use_fused:
+                    agg, new_ef = aggregate_tree_bucketed(
+                        ctx, grads, policies, ef_states=ef, layout=layout)
+                else:
+                    agg, new_ef = aggregate_tree(ctx, grads, policies,
+                                                 ef_states=ef)
             lval = jax.lax.pmean(lval, dp)
             return lval, agg, new_ef
 
@@ -704,7 +714,9 @@ class Fabric:
             gn = jnp.sqrt(sum(jnp.sum(x.astype(jnp.float32) ** 2)
                               for x in jax.tree.leaves(agg)))
             metrics["agg_norm"] = gn
-            new_params, new_opt = optimizer.apply(state.params, agg, state.opt)
+            with jax.named_scope("optimizer.apply"):
+                new_params, new_opt = optimizer.apply(state.params, agg,
+                                                      state.opt)
             return (TrainState(params=new_params, opt=new_opt, ef=new_ef,
                                step=state.step + 1), metrics)
 
@@ -760,10 +772,12 @@ class Fabric:
                cfg, optimizer, loss, use_fused, self.fused_kernels,
                kern_sig, self.num_workers, self.membership_epoch)
         if key not in self._compiled:
-            self._compiled[key] = self.build_step(
-                cfg, optimizer, plan, params_like,
-                with_diagnostics=with_diagnostics, loss=loss, zero1=zero1,
-                grad_accum=grad_accum, fused=use_fused)
+            # a miss inside a training run shows in its trace at that step
+            with jax.profiler.TraceAnnotation("fabric.build_step"):
+                self._compiled[key] = self.build_step(
+                    cfg, optimizer, plan, params_like,
+                    with_diagnostics=with_diagnostics, loss=loss,
+                    zero1=zero1, grad_accum=grad_accum, fused=use_fused)
         return self._compiled[key]
 
     def clear_cache(self) -> None:

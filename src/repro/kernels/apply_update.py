@@ -49,6 +49,7 @@ def unpack_ternary(sign_words: jax.Array, mask_words: jax.Array, *,
                   pl.BlockSpec((wb, LANE), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name="fabric_unpack_ternary",
     )(sign_words, mask_words)
 
 
@@ -82,4 +83,5 @@ def apply_sign_update(param_plane: jax.Array, sign_words: jax.Array,
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name="fabric_apply_sign_update",
     )(param_plane, sign_words, mask_words, scale_arr)

@@ -191,6 +191,7 @@ def _encode_pack_ef_call(g_plane, e_plane, *, interpret: bool):
         out_specs=(pl.BlockSpec((wb, LANE), lambda i: (i, 0)),
                    pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0))),
         interpret=interpret,
+        name="fabric_encode_pack_ef",
     )(g_plane, e_plane)
 
 
@@ -219,6 +220,7 @@ def _vote_combine_call(routed, gate_words, *, num_workers: int,
         out_specs=(pl.BlockSpec((wb, LANE), lambda i: (i, 0)),
                    pl.BlockSpec((wb, LANE), lambda i: (i, 0))),
         interpret=interpret,
+        name="fabric_vote_combine",
     )(routed, gate_words)
 
 
@@ -251,6 +253,7 @@ def _vote_pipeline_call(stack, gate_words, *, num_workers: int, dtype,
                   pl.BlockSpec((wb, LANE), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name="fabric_vote_pipeline",
     )(stack, gate_words)
 
 
@@ -288,6 +291,7 @@ def _ef_residual_call(plane, beta, *, interpret: bool):
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rb, LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name="fabric_ef_residual",
     )(plane, _scalar_operand(beta, plane.dtype))
 
 
@@ -313,6 +317,7 @@ def _int4_quant_call(plane, *, levels: float, interpret: bool):
         out_specs=pl.BlockSpec((rb, LANE), lambda p, i: (i, 0)),
         scratch_shapes=[_smem_scratch()],
         interpret=interpret,
+        name="fabric_int4_quant",
     )(plane)
 
 
@@ -342,6 +347,7 @@ def _threshold_mask_call(plane, thresh, *, interpret: bool):
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rb, LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name="fabric_threshold_mask",
     )(plane, _scalar_operand(thresh, plane.dtype))
 
 
@@ -391,45 +397,57 @@ def fused_packed_vote(g: jax.Array, dp_axes, num_workers: int, *,
     n = g.size
     if not dp_axes:
         # no collective separates the stages: one kernel per bucket
-        g_eff = g if ef is None else g + ef.astype(g.dtype)
-        plane = ref.to_plane(g_eff.reshape(-1))
-        gate = local_gate_words(plane.shape[0] // PACK, ternary=ternary,
-                                gate_phase=gate_phase, gate_mask=gate_mask)
-        u_plane = vote_pipeline(plane[None], gate, num_workers=w,
-                                dtype=jnp.float32, interpret=interpret)
-        u = ref.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
-        new_ef = None if ef is None else \
-            ef_update_fused(g_eff, ef, interpret=interpret)
+        with jax.named_scope("encode"):
+            g_eff = g if ef is None else g + ef.astype(g.dtype)
+            plane = ref.to_plane(g_eff.reshape(-1))
+        with jax.named_scope("vote"):
+            gate = local_gate_words(plane.shape[0] // PACK, ternary=ternary,
+                                    gate_phase=gate_phase,
+                                    gate_mask=gate_mask)
+            u_plane = vote_pipeline(plane[None], gate, num_workers=w,
+                                    dtype=jnp.float32, interpret=interpret)
+        with jax.named_scope("decode"):
+            u = ref.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
+        with jax.named_scope("encode"):  # the residual is the encoder's state
+            new_ef = None if ef is None else \
+                ef_update_fused(g_eff, ef, interpret=interpret)
         return u, new_ef
 
-    if ef is None:
-        plane = ref.to_plane(g.reshape(-1))
-        words = pack_signs(plane, interpret=interpret)
-        g_eff = None
-    else:
-        g_plane = ref.to_plane(g.reshape(-1))
-        e_plane = ref.to_plane(ef.astype(g.dtype).reshape(-1))
-        words, geff_plane = encode_pack_ef(g_plane, e_plane,
-                                           interpret=interpret)
-        g_eff = ref.from_plane(geff_plane, n).reshape(g.shape)
-    r = words.shape[0]
-    rw = ref.shard_rows(r, w)
-    pad_r = rw * w - r
-    if pad_r:
-        words = jnp.pad(words, ((0, pad_r), (0, 0)))
-    routed = jax.lax.all_to_all(words.reshape(w, rw, LANE), dp_axes,
-                                split_axis=0, concat_axis=0, tiled=False)
-    gate = shard_gate_words(dp_axes, rw, ternary=ternary,
-                            gate_phase=gate_phase, gate_mask=gate_mask,
-                            total_rows=r + pad_r)
-    sw, mw = vote_combine(routed, gate, num_workers=w, interpret=interpret)
-    sw_all = jax.lax.all_gather(sw, dp_axes, axis=0, tiled=True)[:r]
-    mw_all = jax.lax.all_gather(mw, dp_axes, axis=0, tiled=True)[:r]
-    u_plane = unpack_ternary(sw_all, mw_all, dtype=jnp.float32,
-                             interpret=interpret)
-    u = ref.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
-    new_ef = None if ef is None else \
-        ef_update_fused(g_eff, ef, interpret=interpret)
+    with jax.named_scope("encode"):
+        if ef is None:
+            plane = ref.to_plane(g.reshape(-1))
+            words = pack_signs(plane, interpret=interpret)
+            g_eff = None
+        else:
+            g_plane = ref.to_plane(g.reshape(-1))
+            e_plane = ref.to_plane(ef.astype(g.dtype).reshape(-1))
+            words, geff_plane = encode_pack_ef(g_plane, e_plane,
+                                               interpret=interpret)
+            g_eff = ref.from_plane(geff_plane, n).reshape(g.shape)
+        r = words.shape[0]
+        rw = ref.shard_rows(r, w)
+        pad_r = rw * w - r
+        if pad_r:
+            words = jnp.pad(words, ((0, pad_r), (0, 0)))
+    with jax.named_scope("exchange"):
+        routed = jax.lax.all_to_all(words.reshape(w, rw, LANE), dp_axes,
+                                    split_axis=0, concat_axis=0, tiled=False)
+    with jax.named_scope("vote"):
+        gate = shard_gate_words(dp_axes, rw, ternary=ternary,
+                                gate_phase=gate_phase, gate_mask=gate_mask,
+                                total_rows=r + pad_r)
+        sw, mw = vote_combine(routed, gate, num_workers=w,
+                              interpret=interpret)
+    with jax.named_scope("exchange"):
+        sw_all = jax.lax.all_gather(sw, dp_axes, axis=0, tiled=True)[:r]
+        mw_all = jax.lax.all_gather(mw, dp_axes, axis=0, tiled=True)[:r]
+    with jax.named_scope("decode"):
+        u_plane = unpack_ternary(sw_all, mw_all, dtype=jnp.float32,
+                                 interpret=interpret)
+        u = ref.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
+    with jax.named_scope("encode"):      # the residual is the encoder's state
+        new_ef = None if ef is None else \
+            ef_update_fused(g_eff, ef, interpret=interpret)
     return u, new_ef
 
 

@@ -64,6 +64,7 @@ def popcount_stack(packed: jax.Array, *, interpret: bool = False) -> jax.Array:
         in_specs=[pl.BlockSpec((w, wb, LANE), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name="fabric_popcount_stack",
     )(packed)
 
 
@@ -110,4 +111,5 @@ def majority_decode(counts: jax.Array, gate_words: jax.Array, *,
         out_specs=(pl.BlockSpec((wb, LANE), lambda i: (i, 0)),
                    pl.BlockSpec((wb, LANE), lambda i: (i, 0))),
         interpret=interpret,
+        name="fabric_majority_decode",
     )(counts, gate_words)

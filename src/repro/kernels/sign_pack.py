@@ -86,4 +86,5 @@ def sign_pack(plane: jax.Array, *, interpret: bool = False) -> jax.Array:
         in_specs=[pl.BlockSpec((wb * PACK, LANE), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((wb, LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name="fabric_sign_pack",
     )(plane)

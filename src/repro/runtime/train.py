@@ -44,6 +44,9 @@ from .fault import (FailureInjector, SimulatedFailure, StepTimer,
 
 log = logging.getLogger("repro.train")
 
+#: a host span in the profiler's trace; costs next to nothing untraced
+_span = jax.profiler.TraceAnnotation
+
 __all__ = ["TrainState", "Trainer", "TrainerConfig", "build_train_step",
            "dp_num_workers"]
 
@@ -171,7 +174,6 @@ class Trainer:
         self.state: TrainState | None = None
         self.history: list[dict] = []
         self.restarts = 0
-        self.traffic_log: list[float] = []
         self._sizes = None
         self._just_restarted = False
 
@@ -233,7 +235,7 @@ class Trainer:
                 self.init_state()
 
         it = iter(self.data)
-        done = int(self.state.step)
+        done = self._read_step()
         while done < num_steps:
             try:
                 done = self._run_until(num_steps, it)
@@ -244,12 +246,17 @@ class Trainer:
                 log.warning("%s -> restart %d (restore + replay)",
                             e, self.restarts)
                 self._recover()
-                done = int(self.state.step)
+                done = self._read_step()
         if self.ckpt is not None:
-            self.ckpt.maybe_save(int(self.state.step), self.state, force=True,
-                                 controller=self.controller)
-            self.ckpt.wait()
+            with _span("trainer.checkpoint"):
+                self.ckpt.maybe_save(self._read_step(), self.state,
+                                     force=True, controller=self.controller)
+                self.ckpt.wait()
         return self.history
+
+    def _read_step(self) -> int:
+        with _span("trainer.sync"):
+            return int(self.state.step)
 
     def _recover(self):
         """Node-failure recovery: restore last durable checkpoint.
@@ -269,11 +276,19 @@ class Trainer:
         self._just_restarted = True
 
     def _run_until(self, num_steps: int, it: Iterator[dict]) -> int:
-        while int(self.state.step) < num_steps:
-            step = int(self.state.step)
-            if self.failure_injector is not None:
-                self.failure_injector.check(step)
+        while self._read_step() < num_steps:
+            step = self._read_step()
+            with jax.profiler.StepTraceAnnotation("trainer.step",
+                                                  step_num=step):
+                self._one_step(step, it)
+        return self._read_step()
 
+    def _one_step(self, step: int, it: Iterator[dict]) -> None:
+        """One iteration of the loop; each stage in a span of its own."""
+        if self.failure_injector is not None:
+            self.failure_injector.check(step)
+
+        with _span("trainer.lookup"):
             plan = self._current_plan()
             # the controller owns the calibration window (single source of
             # truth for warm-up length): compile with diagnostics while it
@@ -281,6 +296,7 @@ class Trainer:
             calibrating = bool(self.controller is not None and getattr(
                 self.controller, "wants_diagnostics", False))
             jitted, b_sh = self._get_step(plan, calibrating)
+        with _span("trainer.data"):
             if hasattr(self.data, "batch_at"):   # deterministic replay
                 batch = self.data.batch_at(step)
             else:
@@ -288,30 +304,33 @@ class Trainer:
             batch = jax.tree.map(
                 lambda a: jax.device_put(jnp.asarray(a), b_sh), batch)
 
-            with StepTimer() as t:
+        with StepTimer() as t:
+            with _span("trainer.dispatch"):
                 self.state, metrics = jitted(self.state, batch)
+            with _span("trainer.sync"):
                 metrics = {k: float(v) for k, v in metrics.items()}
+        with _span("trainer.bookkeeping"):
             self.watchdog.observe(step, t.duration)
-
             metrics["step"] = step
             metrics["plan"] = plan.signature()
             metrics["traffic_ratio"] = plan_traffic_ratio(self._sizes, plan)
-            self.traffic_log.append(metrics["traffic_ratio"])
             self.history.append(metrics)
 
-            if self.controller is not None:
+        if self.controller is not None:
+            with _span("trainer.controller"):
                 telemetry = Telemetry.from_metrics(
                     step, metrics, step_time_s=t.duration,
                     restart=self._just_restarted)
                 self._just_restarted = False
                 self.controller.observe(telemetry)
 
-            if self.ckpt is not None:
+        if self.ckpt is not None:
+            with _span("trainer.checkpoint"):
                 self.ckpt.maybe_save(step + 1, self.state,
                                      extra={"plan": plan.signature()},
                                      controller=self.controller)
-            if step % self.tcfg.log_interval == 0:
+        if step % self.tcfg.log_interval == 0:
+            with _span("trainer.bookkeeping"):
                 log.info("step %d loss %.4f traffic %.4f plan=%s", step,
                          metrics["loss"], metrics["traffic_ratio"],
                          plan.signature()[:48])
-        return int(self.state.step)
