@@ -224,7 +224,13 @@ def main():
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--moe-cf", type=float, default=0.0)
-    ap.add_argument("--remat-policy", default="")
+    ap.add_argument("--remat-policy", default="",
+                    help="override the config's remat_policy: auto (save "
+                         "the layers' un-batched dot outputs when they fit "
+                         "a quarter of the device's memory, else full; the "
+                         "placeholder host devices report no limit, so they "
+                         "take full), full (recompute each layer) or dots "
+                         "(save every dot output)")
     ap.add_argument("--tag-suffix", default="")
     args = ap.parse_args()
 

@@ -69,7 +69,10 @@ class ModelConfig:
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     remat: bool = True                     # activation checkpoint per layer
-    remat_policy: str = "full"             # full | dots (save dot/AR outputs)
+    # auto: save the layers' un-batched dot outputs (projections, MLP) when
+    # they fit a quarter of the device's memory, else full; full: recompute
+    # everything; dots: save every dot output (the TP all-reduce inputs)
+    remat_policy: str = "auto"
 
     # smoke-test reduction hint (None = this IS a reduced config)
     full_size: bool = True

@@ -24,6 +24,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax._src.ad_checkpoint import saved_residuals
 from jax.sharding import PartitionSpec as P
 
 from . import layers as L
@@ -330,20 +331,86 @@ def _logits(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 # full forward (train / prefill)
 # ---------------------------------------------------------------------------
 
-def _ckpt(fn, cfg: ModelConfig):
-    """Per-layer remat with configurable policy.
+#: Share of the first device's ``bytes_limit`` that the scanned layers' saved
+#: dot outputs may take under ``remat_policy="auto"``.  The step's arguments
+#: (weights, optimizer moments) and its other temporaries (gradients, the
+#: head's logits, each layer's recomputed attention scores) need the rest: on
+#: a 16 GB v5e a quarter (4.2 GB) admits qwen3-0.6b at 8 x 512 tokens (2.6 GB
+#: of dots; its compiled step then holds 12.6 GB in all) and rejects it at
+#: 2 x 4096 tokens (5.2 GB) and the 48-layer qwen2.5-14b (15.7 GB).
+SAVED_DOTS_SHARE = 0.25
+
+_SAVE_DOTS = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+
+
+def _device_bytes_limit() -> Optional[int]:
+    """Memory limit of the first addressable device; None where the backend
+    reports none (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+def _batch_shards() -> int:
+    """Shards of the activations' batch axis that the traced shapes do not
+    already show: the data-parallel (non-``model``) axes of the current
+    mesh that are still automatic.  Inside the train step's ``shard_map``
+    those axes are manual and the shapes are per device already."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return math.prod(mesh.shape[a] for a in mesh.axis_names
+                     if a != "model" and a not in mesh.manual_axes)
+
+
+def saved_dot_bytes(fn, carry, xs) -> int:
+    """Bytes per device that ``dots_with_no_batch_dims_saveable`` keeps for
+    the backward pass of one call ``fn(carry, xs)``, found by tracing it:
+    the un-batched dot outputs the backward reads, beyond the arguments and
+    constants that full remat keeps too."""
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    res = saved_residuals(jax.checkpoint(fn, policy=_SAVE_DOTS),
+                          *jax.tree.map(spec, (carry, xs)))
+    n = sum(aval.size * aval.dtype.itemsize for aval, src in res
+            if not src.startswith("from "))
+    return n // _batch_shards()
+
+
+def _remat_policy(fn, cfg: ModelConfig, carry, xs):
+    """The ``jax.checkpoint`` policy of a body scanned over ``xs`` (stacked
+    layers); None recomputes everything."""
+    if cfg.remat_policy == "full":
+        return None
+    if cfg.remat_policy == "dots":
+        return jax.checkpoint_policies.checkpoint_dots
+    if cfg.remat_policy != "auto":
+        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}: "
+                         "expected 'auto', 'full' or 'dots'")
+    limit = _device_bytes_limit()
+    if limit is None:
+        return None
+    n_layers = jax.tree.leaves(xs)[0].shape[0]
+    layer = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                         xs)
+    total = n_layers * saved_dot_bytes(fn, carry, layer)
+    return _SAVE_DOTS if total <= SAVED_DOTS_SHARE * limit else None
+
+
+def _ckpt(fn, cfg: ModelConfig, carry, xs):
+    """Per-layer remat of a body scanned as ``lax.scan(fn, carry, xs)``.
 
     'full' recomputes everything in backward (min memory, but re-runs the
-    layer's TP collectives); 'dots' saves dot outputs — the tensors the
-    SPMD partitioner all-reduces — trading activation memory for a ~1/3
-    cut of the per-layer collective traffic (no recomputed ARs).
+    layer's TP collectives); 'dots' saves every dot output — the tensors
+    the SPMD partitioner all-reduces — trading activation memory for a ~1/3
+    cut of the per-layer collective traffic (no recomputed ARs).  'auto'
+    saves the un-batched dot outputs (the projections and MLP matmuls, not
+    the attention or expert einsums) when all layers' worth of them, per
+    device, fit :data:`SAVED_DOTS_SHARE` of the device's memory, and
+    recomputes everything otherwise or where the device reports no limit.
+    The count is of the shapes the body is traced at: under ``vmap``
+    virtual workers (``ElasticTrainer``) it is one worker's, W times too
+    small.
     """
     if not cfg.remat:
         return fn
-    if cfg.remat_policy == "dots":
-        pol = jax.checkpoint_policies.checkpoint_dots
-        return jax.checkpoint(fn, policy=pol)
-    return jax.checkpoint(fn)
+    return jax.checkpoint(fn, policy=_remat_policy(fn, cfg, carry, xs))
 
 
 def forward(params: dict, cfg: ModelConfig, batch: dict) -> jax.Array:
@@ -371,13 +438,13 @@ def forward(params: dict, cfg: ModelConfig, batch: dict) -> jax.Array:
 
         def enc_body(h, lp):
             return _encoder_layer_forward(lp, h, cfg), None
-        enc_body = _ckpt(enc_body, cfg)
+        enc_body = _ckpt(enc_body, cfg, enc, params["encoder"])
         enc, _ = jax.lax.scan(enc_body, enc, params["encoder"])
 
         def dec_body(h, lp):
             ek, ev = L.cross_kv(lp["cross"], enc, cfg)
             return _decoder_xlayer_forward(lp, h, cfg, ek, ev, positions), None
-        dec_body = _ckpt(dec_body, cfg)
+        dec_body = _ckpt(dec_body, cfg, x, params["layers"])
         x, _ = jax.lax.scan(dec_body, x, params["layers"])
         return _logits(params, cfg, x)
 
@@ -389,9 +456,8 @@ def forward(params: dict, cfg: ModelConfig, batch: dict) -> jax.Array:
     def body(h, xs):
         lp, is_global = xs
         return _layer_forward(lp, h, cfg, is_global, positions), None
-    body = _ckpt(body, cfg)
-    x, _ = jax.lax.scan(body, x, (params["layers"],
-                                  jnp.asarray(flags[n_prefix:])))
+    xs = (params["layers"], jnp.asarray(flags[n_prefix:]))
+    x, _ = jax.lax.scan(_ckpt(body, cfg, x, xs), x, xs)
     return _logits(params, cfg, x)
 
 
