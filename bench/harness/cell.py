@@ -5,8 +5,9 @@ The timed path is the program's own: a ``repro.runtime.Trainer`` built by
 ``repro.fabric.control.plan_presets()``, driven through ``Trainer.run``.
 Set-up builds that one Trainer, drives it from the seed through the
 checked steps (which also warm it up) and hands the same object to the
-window.  The reference follows the checked steps once the window has
-closed and the program's state is freed.
+window.  The reference the configuration names follows the checked steps
+once the window has closed and the program's state is freed, over as many
+data-parallel workers as the program runs.
 """
 from __future__ import annotations
 
@@ -93,7 +94,7 @@ def program_readings(trainer, pool, checked: int, b1: float, voted) -> dict:
     checked steps.
     """
     import jax
-    from reference.model import change_readings, leaf_norm, leaf_paths, \
+    from reference.common import change_readings, leaf_norm, leaf_paths, \
         vote_bits
     marks = {"init_start": time.time()}
     trainer.init_state()
@@ -130,19 +131,21 @@ def program_readings(trainer, pool, checked: int, b1: float, voted) -> dict:
 
 def reference_readings(cell, seed: int, pool, checked: int,
                        matmul: str = "highest") -> dict:
+    """The reference's checked steps: W = the mesh's ``data`` size
+    workers, each on its rows of every global batch, one to a chip."""
     import jax
-    from reference.model import AdamW, Reference, init_weights, leaf_paths
-    if cell.chips != 1:
-        raise ValueError(f"{cell.name} runs on {cell.chips} chips; the "
-                         "reference runs one worker")
+    from reference.common import AdamW, Reference, leaf_paths
+    arch = cell.reference
+    workers = cell.traffic["mesh"][0]
     o = cell.traffic["optimizer"]
-    ref = Reference(cell.config,
+    ref = Reference(arch, cell.config,
                     AdamW(peak_lr=cell.traffic["peak_lr"],
                           warmup_steps=o["warmup_steps"],
                           total_steps=cell.traffic["total_steps"],
                           b1=o["b1"], b2=o["b2"], eps=o["eps"]),
-                    voted=voted_rule(cell), matmul=matmul)
-    shapes = jax.eval_shape(lambda: init_weights(0, ref.s))
+                    voted=voted_rule(cell), matmul=matmul,
+                    devices=jax.devices()[:workers] if workers > 1 else None)
+    shapes = jax.eval_shape(lambda: arch.init_weights(0, ref.s))
     voted = [int(x.size) for p, x in zip(leaf_paths(shapes),
                                          jax.tree.leaves(shapes))
              if ref.voted(p)]
@@ -152,7 +155,7 @@ def reference_readings(cell, seed: int, pool, checked: int,
 
 
 def voted_rule(cell):
-    from reference.model import voted_rule as rule
+    from reference.common import voted_rule as rule
     return rule(cell.traffic["plan"])
 
 
@@ -314,13 +317,16 @@ def _traced(cell, trainer, pool, step, tokens_per_s, peaks, result) -> dict:
         path = trace_reduce.find_trace(logdir)
         if os.environ.get("BENCH_KEEP_TRACE"):
             shutil.copy(path, os.environ["BENCH_KEEP_TRACE"])
-        red = trace_reduce.reduce(path)
+        events = trace_reduce.events_of(path)
     finally:
         shutil.rmtree(logdir, ignore_errors=True)
+    red = trace_reduce.reduce_events(events)
+    chip_ops = trace_reduce.chip_ops(events)
+    del events
     op_class = hlo_ops.classify(red["op_s"], hlo)
-    ctx = {"cell": cell, "trace": red, "op_class": op_class,
-           "steps_traced": n, "hlo": hlo, "peaks": peaks,
-           "tokens_per_s": tokens_per_s}
+    ctx = {"cell": cell, "trace": red, "chip_ops": chip_ops,
+           "op_class": op_class, "steps_traced": n, "hlo": hlo,
+           "peaks": peaks, "tokens_per_s": tokens_per_s}
     values = readers.read_all([m["name"] for m in cell.per_layer], ctx)
     result["device"].update(busy_s=red["busy_s"], window_s=red["window_s"])
     top = sorted(red["op_s"].items(), key=lambda kv: -kv[1])[:10]

@@ -8,7 +8,10 @@ a run (the program's files are untouched):
 * ``update_sign_flipped``: the optimizer's update is applied the wrong
   way (its state, and so the gradient it read, stay right);
 * ``half_batch``: the loss is taken over the first half of each worker's
-  rows only, the mean over those.
+  rows only, the mean over those;
+* ``no_exchange``: the packed sign vote leaves out the exchange between
+  chips: each worker decodes its own vote, with no all-to-all and no
+  all-gather.
 """
 from __future__ import annotations
 
@@ -82,7 +85,22 @@ def half_batch():
         yield
 
 
+@contextlib.contextmanager
+def no_exchange():
+    from repro.core import lowbit
+
+    orig = lowbit._packed_a2a_local
+
+    def alone(g, dp_axes, num_workers, **kwargs):
+        # no data-parallel axis: the one-worker vote, on this worker's signs
+        return orig(g, (), 1, **kwargs)
+
+    with _patched(lowbit, "_packed_a2a_local", alone):
+        yield
+
+
 FAULTS = {"state_unchanged": state_unchanged,
           "update_sign_flipped": update_sign_flipped,
-          "half_batch": half_batch}
+          "half_batch": half_batch,
+          "no_exchange": no_exchange}
 

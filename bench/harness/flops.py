@@ -7,9 +7,24 @@ value matmuls that the mask keeps.
 
 Aggregation bytes (the numerator of ``agg_kernel_roofline``): per chip,
 read each admitted gradient element once, write each decoded update once,
-and write and read the packed sign words (1 bit per element) once.
+and write and read the packed sign words (1 bit per element) once.  That
+holds on any number of chips: each chip encodes and decodes every element,
+and votes on its share of the words.
+
+A configuration's counts live with its plain reference: where the module
+it names (``harness.spec.reference_module``) defines
+``model_flops_per_token`` or ``admitted_elements``, those count it;
+otherwise the dense decoder's formulas below do.  ``root`` is the
+checkout whose ``bench/reference`` holds the module.
 """
 from __future__ import annotations
+
+from . import spec
+
+
+def _own(config: dict, name: str, root):
+    """The reference module's own count ``name``, or None."""
+    return getattr(spec.reference_module(config, root), name, None)
 
 
 def _dims(config: dict):
@@ -32,8 +47,12 @@ def matmul_params_per_token(config: dict) -> int:
     return config["num_hidden_layers"] * layer_matmul_params(config) + d * v
 
 
-def model_flops_per_token(config: dict, seq_len: int) -> float:
+def model_flops_per_token(config: dict, seq_len: int,
+                          root=spec.ROOT) -> float:
     """Training FLOPs per token: forward + backward, no recompute."""
+    own = _own(config, "model_flops_per_token", root)
+    if own is not None:
+        return float(own(config, seq_len))
     _, h, _, hd, _, _ = _dims(config)
     dense = 6 * matmul_params_per_token(config)
     # QK^T and PV: 2 * 2 * S * H * hd per token forward, half kept by the
@@ -42,9 +61,12 @@ def model_flops_per_token(config: dict, seq_len: int) -> float:
     return float(dense + attn)
 
 
-def admitted_elements(config: dict) -> int:
+def admitted_elements(config: dict, root=spec.ROOT) -> int:
     """Elements the low-bit backbone votes on: every layer weight and bias
     that is not a norm (the LM head and embedding stay on FP32)."""
+    own = _own(config, "admitted_elements", root)
+    if own is not None:
+        return int(own(config))
     d, h, kv, hd, _, _ = _dims(config)
     biases = (h * hd + 2 * kv * hd) if config.get("program", {}).get(
         "qkv_bias") else 0
@@ -53,7 +75,7 @@ def admitted_elements(config: dict) -> int:
 
 
 def agg_bytes_per_chip(config: dict, *, grad_bytes: int = 2,
-                       update_bytes: int = 2) -> float:
+                       update_bytes: int = 2, root=spec.ROOT) -> float:
     """Algorithmic HBM bytes of one step's vote datapath on one chip."""
-    n = admitted_elements(config)
+    n = admitted_elements(config, root)
     return n * (grad_bytes + update_bytes) + 2 * n / 8

@@ -3,13 +3,20 @@
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own under ``bench/``; this module
 resolves them by the names ``BENCHMARK.json`` gives, so a new cell is new
-files plus an entry, never an edit here.
+files plus an entry, never an edit here.  A configuration file states the
+program's model (``program``: any field of ``repro.models.ModelConfig``,
+the nested configurations such as ``moe`` as objects) and names its plain
+reference (``reference``: a module under ``bench/reference/``).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 import pathlib
+import sys
+import typing
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -40,6 +47,11 @@ class Cell:
     limits: dict            # bench/limits/<cell>.json ({} when absent)
     end_to_end: tuple       # metric entries of BENCHMARK.json
     per_layer: tuple        # metric entries that this cell reports
+
+    @property
+    def reference(self):
+        """The plain reference module the configuration names."""
+        return reference_module(self.config, self.root)
 
     @property
     def global_batch(self) -> int:
@@ -90,9 +102,56 @@ def model_config(config: dict):
 
     The file holds the source's ``config.json`` keys as they are run;
     ``program`` holds what the source states by its model type (QK norm,
-    QKV bias) and how the program runs it (remat).
+    QKV bias, ``family``, a ``moe`` block) and how the program runs it
+    (remat), by the names of ModelConfig's fields.  ``family`` is
+    ``dense`` unless stated.  A field whose type is a dataclass (``moe``,
+    ``ssm``) is given as an object of that dataclass's fields.
     """
     from repro.models import ModelConfig
     fields = {HF_KEYS[k]: v for k, v in config.items() if k in HF_KEYS}
     fields.update(config.get("program", {}))
-    return ModelConfig(name=config["name"], family="dense", **fields)
+    fields.setdefault("family", "dense")
+    return _build(ModelConfig, dict(fields, name=config["name"]))
+
+
+def _build(cls, values: dict):
+    """``cls(**values)``, each object given for a field typed by a
+    dataclass built into that dataclass the same way."""
+    hints = typing.get_type_hints(cls)
+    out = {}
+    for k, v in values.items():
+        sub = _dataclass_of(hints.get(k))
+        out[k] = _build(sub, v) if sub is not None and isinstance(v, dict) \
+            else v
+    return cls(**out)
+
+
+def _dataclass_of(tp):
+    """The dataclass a field's type names (``X``, ``Optional[X]``), or None."""
+    for t in (tp, *typing.get_args(tp)):
+        if dataclasses.is_dataclass(t):
+            return t
+    return None
+
+
+def reference_module(config: dict, root: pathlib.Path = ROOT):
+    """The plain reference a configuration names with ``"reference"``
+    (``model`` when it names none): ``bench/reference/<name>.py`` of the
+    checkout ``root``.  ``reference/common.py`` says what it provides."""
+    return _load_reference(config.get("reference", "model"),
+                           pathlib.Path(root))
+
+
+@functools.lru_cache(maxsize=None)
+def _load_reference(name: str, root: pathlib.Path):
+    if not name.isidentifier():
+        raise ValueError(f"reference {name!r} is not a module name")
+    path = root / "bench" / "reference" / f"{name}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"no reference module {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_reference_{name}_{abs(hash(str(path)))}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod

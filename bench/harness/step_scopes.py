@@ -41,6 +41,18 @@ def entry_op_names(hlo: str) -> dict:
     return out
 
 
+def all_op_names(hlo: str) -> dict:
+    """Instruction name -> the ``op_name`` of its metadata ("" without
+    one), for the instructions of every computation."""
+    out = {}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line) if line.startswith((" ", "\t")) else None
+        if m is not None:
+            o = _OP_NAME.search(line)
+            out[m.group(1)] = o.group(1) if o else ""
+    return out
+
+
 def scope_of(op_name: str) -> str | None:
     """The step's layer an op belongs to by its scope path: "aggregate"
     (or "aggregate/<stage>" under one of ``AGG_STAGES``), "optimizer",

@@ -9,10 +9,11 @@ time) and the host spans the benchmark writes with
 ``Trainer.run`` around each call into the program).  The window is the
 ``bench.window`` span; device events are clipped to it.
 
-``reduce(path)`` returns a plain dict (see :func:`reduce_events`) that the
-per-layer metric readers take their numbers from.  ``events_of(path)``
-flattens a trace into that dict's input, so a test can run the reduction
-on a small recorded trace without JAX's reader.
+``events_of(path)`` flattens a trace into plain lists, so a test can run
+the reduction on a small recorded trace without JAX's reader.
+``reduce_events`` turns them into the dict that the per-layer metric
+readers take their numbers from, and ``chip_ops`` into the first chip's
+timeline, for the readers that need to know what ran at the same time.
 """
 from __future__ import annotations
 
@@ -137,5 +138,16 @@ def reduce_events(events: dict, *, window_span: str = WINDOW_SPAN,
             "idle_gaps": gaps}
 
 
-def reduce(path: str) -> dict:
-    return reduce_events(events_of(path))
+def chip_ops(events: dict, *, window_span: str = WINDOW_SPAN) -> list:
+    """The first device's op events inside the window, in order of start,
+    as ``[name, start_ns, end_ns]`` clipped to the window: the timeline on
+    which a reader can tell what ran at the same time as what."""
+    spans = [h for h in events["host"] if h[0] == window_span]
+    if not spans or not events["devices"]:
+        return []
+    w0 = min(s for _, s, _ in spans)
+    w1 = max(s + d for _, s, d in spans)
+    first = sorted(events["devices"])[0]
+    out = [[name, max(s, w0), min(s + d, w1)]
+           for name, s, d in events["devices"][first]]
+    return sorted((e for e in out if e[2] > e[1]), key=lambda e: e[1])

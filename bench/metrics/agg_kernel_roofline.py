@@ -10,5 +10,6 @@ def read(ctx):
     ms = load("agg_kernel_ms", ctx["cell"].root / "bench").read(ctx)
     if ms is None:
         return None
-    nbytes = flops.agg_bytes_per_chip(ctx["cell"].config)
+    cell = ctx["cell"]
+    nbytes = flops.agg_bytes_per_chip(cell.config, root=cell.root)
     return 100.0 * nbytes / (ms / 1e3) / ctx["peaks"]["hbm_bytes_per_s"]

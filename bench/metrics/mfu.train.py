@@ -9,6 +9,6 @@ def read(ctx):
     if not tps:
         return None
     cell = ctx["cell"]
-    per_token = flops.model_flops_per_token(cell.config,
-                                            cell.traffic["seq_len"])
+    per_token = flops.model_flops_per_token(
+        cell.config, cell.traffic["seq_len"], root=cell.root)
     return 100.0 * per_token * tps / (cell.chips * ctx["peaks"]["bf16_flops"])

@@ -10,16 +10,17 @@ What it computes, from the seed and the batches alone:
   SwiGLU MLP and a tied or untied head: loss and gradients in float32,
   every matmul at ``precision="highest"``.  Layers are scanned with their
   activations recomputed, and the loss is taken over blocks of rows, so
-  that the published widths fit on one chip;
-* the aggregation of a data-parallel step of one worker: its gradient
-  on the FP32 path, and on the leaves the plan votes on (``voted_rule``)
-  its vote, +1 where its gradient is > 0, else -1;
-* AdamW with linear warm-up, the weights kept in their storage dtypes
-  between steps (each update computed in float32 and cast back, as a
-  bfloat16 weight with no float32 master copy is).
+  that the published widths fit on one chip.
 
 ``matmul="fp8"`` computes each matmul on operands rounded to float8 e4m3
 with one scale per tensor: the lower-precision control that must fail.
+
+This is the reference a configuration gets when it names none.  It
+provides the interface :mod:`reference.common` describes, without counts
+of its own: ``harness.flops`` counts it by the dense formulas.  The
+aggregation over the workers, AdamW and the loop over the checked steps
+are :mod:`reference.common`'s, and the names of it that callers used to
+take from here are imported here.
 
 Nothing here imports the program under test.
 """
@@ -30,11 +31,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-#: float8 e4m3 as ``lax.reduce_precision`` rounds it (4 exponent bits, 3
-#: mantissa bits, IEEE-style: the largest finite value is 240)
-F8_EXP, F8_MANT, F8_MAX = 4, 3, 240.0
+from reference.common import (  # noqa: F401  (re-exported)
+    AdamW, change_readings, einsum_for, leaf_norm, leaf_paths, path_of,
+    vote_bits, voted_rule)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,28 +68,6 @@ def spec_of(config: dict) -> Spec:
                 qk_norm=bool(prog.get("qk_norm", False)),
                 qkv_bias=bool(prog.get("qkv_bias", False)),
                 dtype=config["torch_dtype"])
-
-
-def voted_rule(plan: str):
-    """Which leaves a plan puts on the sign vote, by leaf path.
-
-    ``gbin_packed`` votes every leaf but the embedding, head and norms
-    (which take the FP32 mean); ``fp32`` votes none.
-    """
-    if plan == "fp32":
-        return lambda path: False
-    if plan == "gbin_packed":
-        return lambda path: not any(w in path for w in
-                                    ("embed", "head", "norm"))
-    raise ValueError(f"the reference knows no plan {plan!r}")
-
-
-def path_of(key_path) -> str:
-    return "/".join(str(getattr(k, "key", k)) for k in key_path)
-
-
-def leaf_paths(tree) -> list[str]:
-    return [path_of(kp) for kp, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
 # ---------------------------------------------------------------------------
@@ -145,24 +123,6 @@ def init_weights(seed, s: Spec) -> dict:
 # forward and loss
 # ---------------------------------------------------------------------------
 
-def _fp8(x):
-    """Round to float8 e4m3 with one scale per tensor (straight-through)."""
-    scale = jax.lax.stop_gradient(
-        jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX)
-    q = jax.lax.reduce_precision(x / scale, exponent_bits=F8_EXP,
-                                 mantissa_bits=F8_MANT) * scale
-    return x + jax.lax.stop_gradient(q - x)
-
-
-def _einsum(matmul: str):
-    if matmul == "highest":
-        return lambda eq, a, b: jnp.einsum(eq, a, b, precision="highest")
-    if matmul == "fp8":
-        return lambda eq, a, b: jnp.einsum(eq, _fp8(a), _fp8(b),
-                                           precision="highest")
-    raise ValueError(f"unknown matmul precision {matmul!r}")
-
-
 def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
@@ -213,7 +173,7 @@ def _layer(x, lw, s: Spec, ein):
 def loss(w32: dict, tokens, labels, s: Spec, *, matmul: str = "highest",
          row_block: int = 512):
     """Mean next-token cross entropy of one worker's rows, in float32."""
-    ein = _einsum(matmul)
+    ein = einsum_for(matmul)
     x = jnp.take(w32["embed"]["tok"], tokens, axis=0)
 
     @jax.checkpoint
@@ -239,160 +199,3 @@ def loss(w32: dict, tokens, labels, s: Spec, *, matmul: str = "highest",
     ce = jax.lax.map(block_ce, (rows.reshape(n // blk, blk, s.d),
                                 gold.reshape(n // blk, blk)))
     return jnp.sum(ce) / n
-
-
-# ---------------------------------------------------------------------------
-# the data-parallel step
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class AdamW:
-    peak_lr: float
-    warmup_steps: int
-    total_steps: int
-    b1: float = 0.9
-    b2: float = 0.999
-    eps: float = 1e-8
-    min_ratio: float = 0.1
-
-    def lr(self, t):
-        t = jnp.asarray(t, jnp.float32)
-        warm = t / max(self.warmup_steps, 1)
-        prog = jnp.clip((t - self.warmup_steps)
-                        / max(self.total_steps - self.warmup_steps, 1), 0, 1)
-        cos = self.min_ratio + (1 - self.min_ratio) * 0.5 * (
-            1 + jnp.cos(jnp.pi * prog))
-        return self.peak_lr * jnp.where(t < self.warmup_steps, warm, cos)
-
-
-def leaf_norm(x) -> jax.Array:
-    x = x.astype(jnp.float32)
-    return jnp.sqrt(jnp.sum(x * x))
-
-
-def vote_bits(u) -> tuple:
-    """Packed (u > 0, u < 0) bits of a leaf: the signs of a vote, or the
-    direction of a change."""
-    flat = u.reshape(-1)
-    return jnp.packbits(flat > 0), jnp.packbits(flat < 0)
-
-
-def change_readings(after, before) -> tuple:
-    """Per leaf, the norm and the direction bits of ``after - before``."""
-    d = [a.astype(jnp.float32) - b.astype(jnp.float32)
-         for a, b in zip(after, before)]
-    return [leaf_norm(x) for x in d], [vote_bits(x) for x in d]
-
-
-class Reference:
-    """The first steps of one data-parallel worker of the reference model."""
-
-    def __init__(self, config: dict, opt: AdamW, *, voted,
-                 matmul: str = "highest"):
-        self.s = spec_of(config)
-        self.voted = voted
-        self.opt = opt
-        self.matmul = matmul
-
-    def init(self, seed: int):
-        with jax.default_matmul_precision("highest"):
-            return jax.jit(lambda k: init_weights(k, self.s))(
-                jax.random.PRNGKey(seed))
-
-    def grad_fn(self, w0):
-        """Jitted (w, tokens, labels, first) -> (agg, readings).
-
-        ``agg`` holds each leaf's aggregate: the gradient (float32) on the
-        FP32 path, the vote (int8 -1/+1) elsewhere.  With ``first``
-        (static) the readings of the first step come too.
-        """
-        s = self.s
-        treedef = jax.tree_util.tree_structure(w0)
-        paths = leaf_paths(w0)
-        vg = jax.value_and_grad(
-            lambda w, t, y: loss(w, t, y, s, matmul=self.matmul))
-
-        def fn(w, tokens, labels, first: bool):
-            w32 = [x.astype(jnp.float32) for x in w]
-            tree = jax.tree_util.tree_unflatten(treedef, w32)
-            lossv, grads = vg(tree, tokens, labels)
-            agg, out = [], {"loss": lossv}
-            raw_norm, agg_norm, bits = [], [], []
-            for p, g in zip(paths, jax.tree_util.tree_leaves(grads)):
-                if not self.voted(p):
-                    a = g
-                else:
-                    a = jnp.where(g > 0, 1, -1).astype(jnp.int8)
-                    bits.append(vote_bits(a))
-                agg.append(a)
-                raw_norm.append(leaf_norm(g))
-                agg_norm.append(leaf_norm(a))
-            if first:
-                out.update(raw_norm=raw_norm, agg_norm=agg_norm, bits=bits)
-            return agg, out
-
-        return jax.jit(fn, static_argnums=(3,))
-
-    def update_fn(self):
-        """Jitted AdamW: (w, m, v, agg, t) -> (w, m, v), the weights in
-        their storage dtypes: the update is taken in float32 and cast back,
-        so a bfloat16 weight moves only where it crosses a rounding step."""
-        opt = self.opt
-
-        def fn(w, m, v, agg, t):
-            lr = opt.lr(t)
-            out_w, out_m, out_v = [], [], []
-            for w_i, g, mi, vi in zip(w, agg, m, v):
-                x = w_i.astype(jnp.float32)
-                g = g.astype(jnp.float32)
-                mi = opt.b1 * mi + (1 - opt.b1) * g
-                vi = opt.b2 * vi + (1 - opt.b2) * g * g
-                mhat = mi / (1 - opt.b1 ** t)
-                vhat = vi / (1 - opt.b2 ** t)
-                x = x - lr * mhat / (jnp.sqrt(vhat) + opt.eps)
-                out_w.append(x.astype(w_i.dtype))
-                out_m.append(mi)
-                out_v.append(vi)
-            return out_w, out_m, out_v
-
-        return jax.jit(fn, donate_argnums=(0, 1, 2, 3))
-
-    def steps(self, seed: int, batches: list[dict]) -> dict:
-        """Run ``len(batches)`` steps from the weights of ``seed``.
-
-        Each batch holds the worker's ``tokens`` and ``labels``.  Returns
-        losses, per-leaf norms of the first step's aggregate and raw
-        gradient, the first step's vote bits, and per leaf the norm and
-        the direction bits of the parameters' change over all the steps.
-        The initial weights are drawn again for the change rather than
-        kept, to fit.
-        """
-        zeros = jax.jit(lambda xs: [jnp.zeros(x.shape, jnp.float32)
-                                    for x in xs])
-        with jax.default_matmul_precision("highest"):
-            w0 = self.init(seed)
-            paths = leaf_paths(w0)
-            gjit, ujit = self.grad_fn(w0), self.update_fn()
-            w = jax.tree_util.tree_leaves(w0)
-            del w0
-            m, v = zeros(w), zeros(w)
-            result = {"loss": []}
-            for i, b in enumerate(batches):
-                agg, out = gjit(w, np.asarray(b["tokens"]),
-                                np.asarray(b["labels"]), i == 0)
-                result["loss"].append(float(out["loss"]))
-                if i == 0:
-                    result["agg_norm"] = [float(x) for x in out["agg_norm"]]
-                    result["raw_norm"] = [float(x) for x in out["raw_norm"]]
-                    result["bits"] = [(np.asarray(a), np.asarray(b_))
-                                      for a, b_ in out["bits"]]
-                del out
-                w, m, v = ujit(w, m, v, agg, jnp.float32(i + 1))
-            del m, v
-            w0 = jax.tree_util.tree_leaves(self.init(seed))
-            norm, bits = jax.jit(change_readings)(w, w0)
-            result["change_norm"] = [float(x) for x in norm]
-            result["change_bits"] = [(np.asarray(p), np.asarray(n))
-                                     for p, n in bits]
-        result["paths"] = paths
-        return result

@@ -10,6 +10,7 @@ import pytest
 from conftest import TINY_CONFIG, TINY_TRAFFIC, write_root
 from harness import cell as C
 from harness import spec, traffic
+from reference import common
 from reference import model as R
 
 QWEN2_LIKE = dict(copy.deepcopy(TINY_CONFIG), name="tiny-bias",
@@ -66,8 +67,8 @@ def test_vote_rules():
 def test_voted_leaves_carry_a_direction():
     """A voted leaf's first aggregate is +-1: its norm is sqrt(size)."""
     config = dict(TINY_CONFIG, torch_dtype="float32")
-    ref = R.Reference(config, R.AdamW(3e-4, 100, 1000),
-                      voted=R.voted_rule("gbin_packed"))
+    ref = common.Reference(R, config, R.AdamW(3e-4, 100, 1000),
+                           voted=R.voted_rule("gbin_packed"))
     w0 = ref.init(5)
     rng = np.random.default_rng(1)
     batch = {"tokens": rng.integers(0, 512, (2, 16)).astype(np.int32),
@@ -110,8 +111,8 @@ def test_update_rounds_to_the_storage_dtype():
     than half its rounding step leaves it where it was, as it leaves the
     program's weight with no float32 master copy."""
     config = dict(TINY_CONFIG)
-    ref = R.Reference(config, R.AdamW(3e-4, 100, 1000),
-                      voted=R.voted_rule("gbin_packed"))
+    ref = common.Reference(R, config, R.AdamW(3e-4, 100, 1000),
+                           voted=R.voted_rule("gbin_packed"))
     w = jax.tree.leaves(ref.init(5))
     w0 = [np.asarray(x) for x in w]
     zeros = [jnp.zeros(x.shape, jnp.float32) for x in w]
