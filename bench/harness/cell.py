@@ -22,7 +22,8 @@ import time
 
 import numpy as np
 
-from . import checks, faults, hlo_ops, readers, spec, trace_reduce, traffic
+from . import (checks, faults, hlo_ops, readers, spec, step_scopes,
+               trace_reduce, traffic)
 
 
 class NoChip(RuntimeError):
@@ -334,6 +335,9 @@ def _traced(cell, trainer, pool, step, tokens_per_s, peaks, result) -> dict:
         "device_ops": [[f"{k} ({op_class.get(k, 'other')})", v]
                        for k, v in top],
         "idle_gaps": red["idle_gaps"][:10]}
-    log("trace: " + json.dumps({"window_s": red["window_s"],
-                                "busy_s": red["busy_s"], **values}))
+    kernels = step_scopes.kernel_seconds(red["op_s"], op_class, hlo)
+    log("trace: " + json.dumps({
+        "window_s": red["window_s"], "busy_s": red["busy_s"], **values,
+        "kernel_ms_by_scope": {k or "(none)": 1e3 * v / n
+                               for k, v in sorted(kernels.items())}}))
     return values

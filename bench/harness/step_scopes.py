@@ -83,6 +83,26 @@ def scope_seconds(op_s: dict, hlo: str) -> dict | None:
     return out or None
 
 
+def kernel_seconds(op_s: dict, op_class: dict, hlo: str) -> dict:
+    """Seconds of the traced Mosaic kernels (``op_class`` "kernel", of
+    :func:`hlo_ops.classify`) by the scope their instruction's metadata
+    names (:func:`scope_of`), in any computation; "" for a kernel under
+    no scope."""
+    names = all_op_names(hlo)
+    out = {}
+    for name, s in op_s.items():
+        if op_class.get(name) == "kernel":
+            scope = scope_of(names.get(name, "")) or ""
+            out[scope] = out.get(scope, 0.0) + s
+    return out
+
+
+def in_scope(key: str, scope: str) -> bool:
+    """Whether ``key`` of :func:`scope_of` is ``scope`` or one of its
+    sub-scopes (``aggregate/encode`` under ``aggregate``)."""
+    return key == scope or key.startswith(scope + "/")
+
+
 def scope_ms(ctx, scope: str):
     """Device ms per traced step under ``scope``, its sub-scopes
     (``aggregate/encode`` under ``aggregate``) included; None when the
@@ -91,6 +111,5 @@ def scope_ms(ctx, scope: str):
     s = scope_seconds(t["op_s"], ctx["hlo"]) if t else None
     if not s:
         return None
-    total = sum(v for k, v in s.items()
-                if k == scope or k.startswith(scope + "/"))
+    total = sum(v for k, v in s.items() if in_scope(k, scope))
     return 1e3 * total / ctx["steps_traced"]

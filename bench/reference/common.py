@@ -63,17 +63,36 @@ def einsum_for(matmul: str):
     raise ValueError(f"unknown matmul precision {matmul!r}")
 
 
+#: a leaf takes the FP32 mean where a whole segment of its path is one of
+#: these: the head and the MoE routers, whose gradients are small and
+#: decide the output
+FP32_SEGMENTS = frozenset({"head", "lm_head", "classifier", "logits",
+                           "router", "gate_weight", "gate_weights"})
+
+#: ... or where its path holds one of these anywhere: norms, biases and
+#: scales, and the embedding and input projections
+FP32_SUBSTRINGS = ("norm", "bias", "scale", "ln_", "layernorm",
+                   "embed", "wte", "wpe", "patch_proj", "frontend")
+
+
+def on_fp32_path(path: str) -> bool:
+    """Whether the low-bit backbone plan keeps the leaf at ``path`` on the
+    FP32 mean.  A list index may print as ``0`` or ``[0]``: either is a
+    segment of its own and matches nothing."""
+    return (not FP32_SEGMENTS.isdisjoint(path.split("/"))
+            or any(w in path for w in FP32_SUBSTRINGS))
+
+
 def voted_rule(plan: str):
     """Which leaves a plan puts on the sign vote, by leaf path.
 
-    ``gbin_packed`` votes every leaf but the embedding, head and norms
-    (which take the FP32 mean); ``fp32`` votes none.
+    ``gbin_packed`` votes every leaf but those :func:`on_fp32_path` keeps
+    on the FP32 mean; ``fp32`` votes none.
     """
     if plan == "fp32":
         return lambda path: False
     if plan == "gbin_packed":
-        return lambda path: not any(w in path for w in
-                                    ("embed", "head", "norm"))
+        return lambda path: not on_fp32_path(path)
     raise ValueError(f"the reference knows no plan {plan!r}")
 
 

@@ -78,11 +78,10 @@ def _normal(key, shape):
     return jax.random.normal(key, shape)
 
 
-def _layer_weights(key, s: Spec) -> dict:
+def _attn_weights(key, s: Spec) -> dict:
     dt = jnp.dtype(s.dtype)
-    d, h, kv, hd, f = s.d, s.heads, s.kv, s.hd, s.ff
-    k_attn, k_mlp, _, _ = jax.random.split(key, 4)
-    ka = jax.random.split(k_attn, 4)
+    d, h, kv, hd = s.d, s.heads, s.kv, s.hd
+    ka = jax.random.split(key, 4)
     std = 1.0 / math.sqrt(d)
     attn = {"wq": (_normal(ka[0], (d, h * hd)) * std).astype(dt),
             "wk": (_normal(ka[1], (d, kv * hd)) * std).astype(dt),
@@ -95,12 +94,23 @@ def _layer_weights(key, s: Spec) -> dict:
     if s.qk_norm:
         attn["q_norm"] = {"scale": jnp.ones((hd,), jnp.float32)}
         attn["k_norm"] = {"scale": jnp.ones((hd,), jnp.float32)}
-    km = jax.random.split(k_mlp, 3)
-    mlp = {"w_gate": (_normal(km[0], (d, f)) * std).astype(dt),
-           "w_up": (_normal(km[1], (d, f)) * std).astype(dt),
-           "w_down": (_normal(km[2], (f, d)) / math.sqrt(f)).astype(dt)}
-    return {"norm1": {"scale": jnp.ones((d,), jnp.float32)}, "attn": attn,
-            "norm2": {"scale": jnp.ones((d,), jnp.float32)}, "mlp": mlp}
+    return attn
+
+
+def _mlp_weights(key, d: int, f: int, dt) -> dict:
+    km = jax.random.split(key, 3)
+    std = 1.0 / math.sqrt(d)
+    return {"w_gate": (_normal(km[0], (d, f)) * std).astype(dt),
+            "w_up": (_normal(km[1], (d, f)) * std).astype(dt),
+            "w_down": (_normal(km[2], (f, d)) / math.sqrt(f)).astype(dt)}
+
+
+def _layer_weights(key, s: Spec) -> dict:
+    k_attn, k_mlp, _, _ = jax.random.split(key, 4)
+    return {"norm1": {"scale": jnp.ones((s.d,), jnp.float32)},
+            "attn": _attn_weights(k_attn, s),
+            "norm2": {"scale": jnp.ones((s.d,), jnp.float32)},
+            "mlp": _mlp_weights(k_mlp, s.d, s.ff, jnp.dtype(s.dtype))}
 
 
 def init_weights(seed, s: Spec) -> dict:
@@ -138,7 +148,8 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(x, lw, s: Spec, ein):
+def _attention(x, lw, s: Spec, ein):
+    """The attention half of a layer, its residual included."""
     b, t, _ = x.shape
     g = s.heads // s.kv
     a = lw["attn"]
@@ -162,23 +173,33 @@ def _layer(x, lw, s: Spec, ein):
     scores = jnp.where(causal, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     o = ein("bkgst,btkh->bskgh", probs, v).reshape(b, t, s.heads * s.hd)
-    x = x + ein("bte,ed->btd", o, a["wo"])
-    m = lw["mlp"]
-    h = _rms(x, lw["norm2"]["scale"], s.eps)
+    return x + ein("bte,ed->btd", o, a["wo"])
+
+
+def _swiglu(h, m, ein):
     u = jax.nn.silu(ein("btd,df->btf", h, m["w_gate"])) \
         * ein("btd,df->btf", h, m["w_up"])
-    return x + ein("btf,fd->btd", u, m["w_down"])
+    return ein("btf,fd->btd", u, m["w_down"])
+
+
+def _layer(x, lw, s: Spec, ein):
+    x = _attention(x, lw, s, ein)
+    h = _rms(x, lw["norm2"]["scale"], s.eps)
+    return x + _swiglu(h, lw["mlp"], ein)
 
 
 def loss(w32: dict, tokens, labels, s: Spec, *, matmul: str = "highest",
-         row_block: int = 512):
-    """Mean next-token cross entropy of one worker's rows, in float32."""
+         row_block: int = 512, layer=_layer):
+    """Mean next-token cross entropy of one worker's rows, in float32.
+
+    ``layer(x, lw, s, ein) -> x`` is one scanned layer; a reference whose
+    layers differ passes its own."""
     ein = einsum_for(matmul)
     x = jnp.take(w32["embed"]["tok"], tokens, axis=0)
 
     @jax.checkpoint
     def body(x, lw):
-        return _layer(x, lw, s, ein), None
+        return layer(x, lw, s, ein), None
 
     x, _ = jax.lax.scan(body, x, w32["layers"])
     x = _rms(x, w32["final_norm"]["scale"], s.eps)

@@ -176,8 +176,9 @@ def test_a_new_family_is_new_files_and_an_entry(tmp_path):
     pool = traffic.pool_for(cell, 3)
     ref = C.reference_readings(cell, 3, pool, 2)
     assert ref["paths"] == ["embed/tok", "layers/experts", "layers/router"]
-    assert len(ref["loss"]) == 2 and len(ref["bits"]) == 2
-    assert ref["voted_sizes"] == [4 * 32 * 32, 32 * 4]
+    # the router takes the FP32 mean, as the program's plan gives it
+    assert len(ref["loss"]) == 2 and len(ref["bits"]) == 1
+    assert ref["voted_sizes"] == [4 * 32 * 32]
 
 
 def test_family_defaults_to_dense_and_names_are_checked(tmp_path):

@@ -122,3 +122,73 @@ def test_recorded_trace_reduces_as_before():
         ["$array.py:631 _value", 0.004829946],
         ["$array.py:631 _value", 0.004608055],
         ["tpu::System::TransferFromDevice=>IssueEvent", 0.004128764]]
+
+
+def _mosaic(name, op_name, indent="  "):
+    meta = f', metadata={{op_name="{op_name}"}}' if op_name else ""
+    return (f'{indent}%{name} = u32[8,128]{{1,0}} custom-call(%w), '
+            f'custom_call_target="tpu_custom_call"{meta}')
+
+
+#: a step with one Mosaic call under each of its scopes, one under none,
+#: and one under the aggregation inside a loop's body
+KERNEL_HLO = "\n".join([
+    "HloModule jit_step_fn, entry_computation_layout={()->()}",
+    "",
+    "%body.1 (p: (s32[])) -> (s32[]) {",
+    _mosaic("fabric_pack.9", "jit(step_fn)/fabric.aggregate/encode/"
+            "fabric_pack/pallas_call"),
+    "}",
+    "",
+    "ENTRY %main.9 (t: (s32[])) -> f32[8] {",
+    '  %while.1 = (s32[]) while(%t), condition=%c, body=%body.1, '
+    'metadata={op_name="jit(step_fn)/fabric.aggregate/encode/while"}',
+    _mosaic("fabric_vote_combine.2", "jit(step_fn)/shard_map/"
+            "fabric.aggregate/shard_map/vote/fabric_vote_combine/pallas_call"),
+    _mosaic("fabric_unscoped.3", "jit(step_fn)/fabric.aggregate/"
+            "pallas_call"),
+    _mosaic("gmm.4", "jit(step_fn)/shard_map/jvp(model)/moe/gmm/"
+            "pallas_call"),
+    _mosaic("gmm.5", "jit(step_fn)/shard_map/transpose(jvp(model))/moe/"
+            "gmm/pallas_call"),
+    _mosaic("adam.6", "jit(step_fn)/optimizer.apply/pallas_call"),
+    _mosaic("custom-call.7", ""),
+    '  ROOT %fusion.8 = f32[8]{0} fusion(%x), kind=kLoop, calls=%f8, '
+    'metadata={op_name="jit(step_fn)/fabric.aggregate/vote/add"}',
+    "}",
+])
+
+KERNEL_OP_S = {"while.1": 0.0105, "fabric_pack.9": 0.010,
+               "fabric_vote_combine.2": 0.006, "fabric_unscoped.3": 0.002,
+               "gmm.4": 0.100, "gmm.5": 0.200, "adam.6": 0.030,
+               "custom-call.7": 0.004, "fusion.8": 0.001}
+
+
+def test_kernel_seconds_by_scope():
+    from harness import hlo_ops
+    op_class = hlo_ops.classify(KERNEL_OP_S, KERNEL_HLO)
+    assert sorted(k for k, c in op_class.items() if c == "kernel") == [
+        "adam.6", "custom-call.7", "fabric_pack.9", "fabric_unscoped.3",
+        "fabric_vote_combine.2", "gmm.4", "gmm.5"]
+    got = step_scopes.kernel_seconds(KERNEL_OP_S, op_class, KERNEL_HLO)
+    assert got == pytest.approx({
+        "aggregate/encode": 0.010, "aggregate/vote": 0.006,
+        "aggregate": 0.002, "forward": 0.100, "backward": 0.200,
+        "optimizer": 0.030, "": 0.004})
+
+
+def test_agg_kernel_ms_counts_the_vote_datapath_only():
+    """The model's own Mosaic kernels (forward and backward), the
+    optimizer's and an unscoped one are not the vote datapath's; its
+    kernels count wherever their computation sits."""
+    from harness import hlo_ops
+    ctx = {"trace": {"op_s": KERNEL_OP_S}, "hlo": KERNEL_HLO,
+           "op_class": hlo_ops.classify(KERNEL_OP_S, KERNEL_HLO),
+           "steps_traced": 2}
+    got = readers.load("agg_kernel_ms", BENCH).read(ctx)
+    assert got == pytest.approx(1e3 * (0.010 + 0.006 + 0.002) / 2)
+    # with no kernel under the aggregation the reader is silent
+    no_agg = {k: v for k, v in KERNEL_OP_S.items()
+              if not k.startswith("fabric_")}
+    ctx["trace"] = {"op_s": no_agg}
+    assert readers.load("agg_kernel_ms", BENCH).read(ctx) is None
