@@ -48,6 +48,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import kernels as K
 from ..kernels import fused as KF
+from ..kernels.ops import fully_manual
 from .modes import AggregationMode, Schedule
 
 Axes = Sequence[str] | str
@@ -189,26 +190,6 @@ def _packed_a2a_local(g: jax.Array, dp_axes: Axes, num_workers: int, *,
         u = K.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
     with jax.named_scope("encode"):      # the residual is the encoder's state
         return u, _ef_update(g_eff, ef)
-
-
-def fully_manual(fn, in_specs=P(), out_specs=P()):
-    """``fn`` under a shard_map manual over every mesh axis still automatic.
-
-    Mosaic kernels cannot be partitioned automatically, so every Pallas
-    call in a train step runs where no mesh axis is left to the
-    partitioner.  The train step's shard_map is manual over the DP axes
-    only; this adds the remaining ones (``'model'``) for the span of
-    ``fn``.  Specs default to replicated, so a leaf sharded over an auto
-    axis is gathered first — the semantics of the un-sharded leaf.
-    Outside any mesh (host-local sessions, vmapped virtual workers)
-    ``fn`` is returned as is.
-    """
-    mesh = jax.sharding.get_abstract_mesh()
-    rest = frozenset(a for a in mesh.axis_names if a not in mesh.manual_axes)
-    if not rest:
-        return fn
-    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
-                         axis_names=rest, check_vma=False)
 
 
 def lowbit_packed_a2a(g: jax.Array, dp_axes: Axes, num_workers: int, *,

@@ -28,9 +28,10 @@ import dataclasses
 import jax.numpy as jnp
 
 from ..core.lowbit import (LeafPolicy, _ef_inject, _ef_update,
-                           fp32_allreduce, fully_manual, lowbit_packed_a2a,
+                           fp32_allreduce, lowbit_packed_a2a,
                            lowbit_vote_psum, sign_of_mean)
 from ..core.modes import Schedule, wire_schedule
+from ..kernels.ops import fully_manual
 from .codecs import get_codec, resolve_leaf_gate_mask, ring_wire_bytes
 from .registry import AggregationContext, get_schedule, register_schedule
 

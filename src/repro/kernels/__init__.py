@@ -10,16 +10,16 @@ the one-kernel-per-bucket drivers for the vote chain and the extension
 codec quantizers.
 """
 from . import ref
-from .ops import (LANE, PACK, apply_sign_update, from_plane,
+from .ops import (LANE, PACK, apply_sign_update, from_plane, fully_manual,
                   gate_words_from_mask, interpret_default, majority_decode,
-                  pack_signs, padded_len, popcount_stack, shard_rows,
+                  on_tpu, pack_signs, padded_len, popcount_stack, shard_rows,
                   ternary_gate_words, to_plane, unpack_ternary)
 from .fused import (Int4KernelSet, KernelSet, TopKKernelSet, VoteKernelSet,
                     fused_packed_vote, vote_kernel_set)
 
 __all__ = [
-    "ref", "LANE", "PACK", "apply_sign_update", "from_plane",
-    "gate_words_from_mask", "interpret_default", "majority_decode",
+    "ref", "LANE", "PACK", "apply_sign_update", "from_plane", "fully_manual",
+    "gate_words_from_mask", "interpret_default", "majority_decode", "on_tpu",
     "pack_signs", "padded_len", "popcount_stack", "shard_rows",
     "ternary_gate_words",
     "to_plane", "unpack_ternary",

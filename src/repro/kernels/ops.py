@@ -15,6 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import ref
 from .ref import LANE, PACK, padded_len, shard_rows, to_plane, from_plane
@@ -25,7 +26,8 @@ from .apply_update import (unpack_ternary as _unpack_pallas,
                            apply_sign_update as _apply_pallas)
 
 __all__ = [
-    "interpret_default", "pack_signs", "popcount_stack", "majority_decode",
+    "interpret_default", "on_tpu", "fully_manual", "pack_signs",
+    "popcount_stack", "majority_decode",
     "unpack_ternary", "apply_sign_update", "ternary_gate_words",
     "gate_words_from_mask", "to_plane", "from_plane", "padded_len",
     "shard_rows", "LANE", "PACK",
@@ -36,6 +38,32 @@ __all__ = [
 def interpret_default() -> bool:
     """Pallas interpret mode: True off-TPU (kernels are TPU-targeted)."""
     return jax.default_backend() != "tpu"
+
+
+def on_tpu() -> bool:
+    """Whether kernels left at their default compile with Mosaic for the
+    TPU (not the interpreter or the CPU oracle)."""
+    return not interpret_default()
+
+
+def fully_manual(fn, in_specs=P(), out_specs=P()):
+    """``fn`` under a shard_map manual over every mesh axis still automatic.
+
+    Mosaic kernels cannot be partitioned automatically, so every Pallas
+    call in a train step runs where no mesh axis is left to the
+    partitioner.  The train step's shard_map is manual over the DP axes
+    only; this adds the remaining ones (``'model'``) for the span of
+    ``fn``.  Specs default to replicated, so a leaf sharded over an auto
+    axis is gathered first — the semantics of the un-sharded leaf.
+    Outside any mesh (host-local sessions, vmapped virtual workers)
+    ``fn`` is returned as is.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    rest = frozenset(a for a in mesh.axis_names if a not in mesh.manual_axes)
+    if not rest:
+        return fn
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=rest, check_vma=False)
 
 
 def _mode(interpret) -> str:

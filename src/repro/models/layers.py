@@ -16,8 +16,10 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.sharding import PartitionSpec as P
 
+from ..kernels import ops as kernel_ops
 from .config import ModelConfig, MoEConfig
 
 
@@ -63,13 +65,17 @@ def rmsnorm(p: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 # rotary position embedding
 # ---------------------------------------------------------------------------
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: (..., S) int32."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scale: float = 1.0) -> jax.Array:
+    """x: (..., S, H, hd); positions: (..., S) int32.  ``scale`` multiplies
+    the rotated values in float32, before their one rounding to x's dtype."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[..., None].astype(jnp.float32) * freqs        # (..., S, half)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1)
@@ -112,7 +118,8 @@ def attention_pspecs(cfg: ModelConfig) -> dict:
     return p
 
 
-def _qkv(p: dict, x: jax.Array, cfg: ModelConfig, positions):
+def _qkv(p: dict, x: jax.Array, cfg: ModelConfig, positions,
+         q_scale: float = 1.0):
     b, s, d = x.shape
     h, k, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     q = x @ p["wq"]
@@ -126,7 +133,7 @@ def _qkv(p: dict, x: jax.Array, cfg: ModelConfig, positions):
     if cfg.qk_norm:
         q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
         kk = rmsnorm(p["k_norm"], kk, cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta, q_scale)
     kk = rope(kk, positions, cfg.rope_theta)
     return q, kk, v
 
@@ -153,6 +160,8 @@ def _gqa_out(scores: jax.Array, v: jax.Array, cfg: ModelConfig) -> jax.Array:
 FLASH_SEQ_THRESHOLD = 2048
 FLASH_BLOCK_Q = 1024
 FLASH_BLOCK_K = 1024
+#: largest square block of the fused Pallas attention kernel
+FUSED_ATTN_BLOCK = 512
 
 
 def _mask_block(iq: jax.Array, jk: jax.Array, *, causal: bool,
@@ -178,8 +187,11 @@ def _flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     carrying (running max, denominator, weighted accumulator).  This keeps
     the 32k/500k-token cells compilable without a quadratic score buffer —
     the flash-attention recurrence expressed in pure lax (XLA fuses it per
-    block; a Pallas attention kernel is an orthogonal optimization to the
-    paper's contribution and intentionally out of scope, see DESIGN.md).
+    block).  :func:`attn_forward` takes it above
+    :data:`FLASH_SEQ_THRESHOLD`, on every backend and for every mask; at or
+    below it, causal unwindowed attention in the TPU train step runs the
+    same recurrence as JAX's bundled splash Pallas kernel (see
+    :func:`_fused_attention_block` and DESIGN.md §13).
     """
     b, s, kv, g, hd = q.shape
     t = k.shape[1]
@@ -235,6 +247,65 @@ def _flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return outs[:, :s].astype(q.dtype)
 
 
+def _fused_attention_block(cfg: ModelConfig, s: int, causal: bool):
+    """Block of the fused Pallas attention kernel for a length-``s``
+    sequence, or None where :func:`attn_forward` keeps its dense branch.
+
+    The block is ``min(s, FUSED_ATTN_BLOCK)``.  The kernel runs where
+    kernels compile with Mosaic (:func:`repro.kernels.ops.on_tpu`), for
+    causal attention without a sliding window, ``s`` at most
+    :data:`FLASH_SEQ_THRESHOLD` and a multiple of the block and of 128, a
+    head dim of whole 128-lane tiles, whole query groups per KV head, and
+    only inside a shard_map whose mesh axes still automatic all have size
+    1 (the train step with no tensor parallelism over heads).  Outside a
+    manual region the mesh is not visible to the trace (a jit over
+    NamedShardings), so the kernel is not taken there.
+    """
+    block = min(s, FUSED_ATTN_BLOCK)
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = [n for n, size in zip(mesh.axis_names, mesh.axis_sizes)
+            if n not in mesh.manual_axes and size > 1]
+    ok = (kernel_ops.on_tpu() and causal and cfg.sliding_window is None
+          and s <= FLASH_SEQ_THRESHOLD and s % block == 0
+          and block % 128 == 0 and cfg.hd % 128 == 0
+          and cfg.num_heads % cfg.num_kv_heads == 0
+          and bool(mesh.manual_axes) and not auto)
+    return block if ok else None
+
+
+def _fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                            block: int, interpret: bool = False
+                            ) -> jax.Array:
+    """Causal GQA attention as JAX's bundled splash flash kernel.
+
+    q: (B,S,H,hd); k, v: (B,S,KV,hd) with ``H % KV == 0`` (the kernel
+    maps each query head to its KV head; k and v are not repeated).
+    Returns (B,S,H*hd), as :func:`_dense_attention` does.  q arrives
+    scaled by 1/sqrt(hd), since the kernel does not scale:
+    :func:`attn_forward` folds the scale into RoPE's float32 product, so q
+    is rounded to its dtype once.  Heads-major per sequence, the kernel vmapped over the
+    batch, one fused backward kernel for dQ, dK and dV.  Scores accumulate
+    in float32 from the operands' dtype and the softmax statistics are
+    float32; the kernel sums the values with float32 probabilities where
+    the dense branch rounds them to the values' dtype.  Mosaic kernels
+    cannot be partitioned, so the call runs under
+    :func:`repro.kernels.ops.fully_manual`.
+    """
+    b, s, h, hd = q.shape
+    mask = splash.MultiHeadMask([splash.CausalMask((s, s))] * h)
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_q_dkv=block,
+        block_kv_dkv=block, use_fused_bwd_kernel=True)
+    kernel = splash.make_splash_mha_single_device(
+        mask, block_sizes=sizes, interpret=interpret)
+
+    def run(q, k, v):
+        heads = lambda a: a.transpose(0, 2, 1, 3)
+        return heads(jax.vmap(kernel)(heads(q), heads(k), heads(v)))
+
+    return kernel_ops.fully_manual(run)(q, k, v).reshape(b, s, h * hd)
+
+
 def attn_forward(p: dict, x: jax.Array, cfg: ModelConfig, *,
                  is_global=True, positions=None, causal: bool = True
                  ) -> jax.Array:
@@ -242,25 +313,44 @@ def attn_forward(p: dict, x: jax.Array, cfg: ModelConfig, *,
 
     ``is_global`` may be a traced bool (scan-over-layers with a per-layer
     local/global pattern): both masks are cheap, only one set of einsums.
-    Long sequences take the blockwise flash path automatically.
+    The core (scores, softmax and the value sum) runs under the
+    ``attention`` scope in one of three branches: long sequences take the
+    blockwise lax flash path; on the TPU, inputs that
+    :func:`_fused_attention_block` admits take the fused Pallas kernel;
+    everything else the dense score/softmax/PV einsums.
     """
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    q, k, v = _qkv(p, x, cfg, positions)
     kv, hd = cfg.num_kv_heads, cfg.hd
     g = cfg.num_heads // kv
+    block = _fused_attention_block(cfg, s, causal)
+    q_scale = 1.0 if block is None else 1.0 / math.sqrt(hd)
+    q, k, v = _qkv(p, x, cfg, positions, q_scale)
 
-    if s > FLASH_SEQ_THRESHOLD:
-        q5 = q.reshape(b, s, kv, g, hd)
-        out = _flash_attention(q5, k, v, causal=causal,
-                               window=cfg.sliding_window,
-                               is_global=is_global,
-                               scale=1.0 / math.sqrt(hd))
-        out = out.reshape(b, s, kv * g * hd)
-        out = shard(out, None, None, "model")
-        return out @ p["wo"]
+    with jax.named_scope("attention"):
+        if s > FLASH_SEQ_THRESHOLD:
+            q5 = q.reshape(b, s, kv, g, hd)
+            out = _flash_attention(q5, k, v, causal=causal,
+                                   window=cfg.sliding_window,
+                                   is_global=is_global,
+                                   scale=1.0 / math.sqrt(hd))
+            out = out.reshape(b, s, kv * g * hd)
+        elif block is not None:
+            out = _fused_causal_attention(q, k, v, block=block)
+        else:
+            out = _dense_attention(q, k, v, cfg, causal=causal,
+                                   is_global=is_global)
+    out = shard(out, None, None, "model")
+    return out @ p["wo"]
 
+
+def _dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     cfg: ModelConfig, *, causal: bool, is_global
+                     ) -> jax.Array:
+    """Materialised (B,K,G,S,S) scores, float32 softmax, value sum.
+    q: (B,S,H,hd); k, v: (B,S,KV,hd).  Returns (B,S,H*hd)."""
+    s = q.shape[1]
     scores = _gqa_scores(q, k, cfg)                     # (B,K,G,S,T)
     i = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
@@ -271,9 +361,7 @@ def attn_forward(p: dict, x: jax.Array, cfg: ModelConfig, *,
         mask = jnp.where(glob, mask, local)
     scores = jnp.where(mask[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_out(probs, v, cfg)
-    out = shard(out, None, None, "model")
-    return out @ p["wo"]
+    return _gqa_out(probs, v, cfg)
 
 
 def attn_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache_k, cache_v,

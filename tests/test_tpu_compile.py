@@ -158,3 +158,84 @@ def test_fused_packed_vote_compiles_on_four_chips(topo, n, ternary, ef):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
+
+
+def _one_layer_qwen3():
+    import dataclasses
+
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("qwen3_0p6b"), num_layers=1,
+                               vocab_size=1024)
+
+
+def _mosaic_op_names(hlo_text: str) -> list:
+    """The ``op_name`` of every Mosaic call in ``hlo_text``.  A kernel
+    that passes metadata prints it as indented JSON, so its instruction
+    runs on to lines that start with neither an indent nor ``%``; they
+    are joined to it first."""
+    import re
+    instrs = []
+    for line in hlo_text.splitlines():
+        if line.startswith((" ", "\t")) or not instrs:
+            instrs.append(line)
+        elif not re.match(r"(ENTRY\s+)?%|\}$", line):
+            instrs[-1] += "\n" + line
+    return [re.search(r'(?<![\w"])metadata=\{op_name="([^"]*)"', i).group(1)
+            for i in instrs if 'custom_call_target="tpu_custom_call"' in i]
+
+
+def test_decoder_layer_attention_kernel_compiles(topo, monkeypatch):
+    """One qwen3-width decoder layer's loss and gradients under the
+    ``"auto"`` remat policy, inside a shard_map manual over the data axis
+    of a (1, 1) mesh as the train step runs it: the attention core lowers
+    to the splash flash kernels, which carry the ``attention`` scope in
+    the forward pass and under ``transpose(jvp(`` in the backward pass."""
+    from repro.kernels import ops as kernel_ops
+    from repro.models import transformer as T
+
+    # the TPU's answers, which the CPU process lacks
+    monkeypatch.setattr(kernel_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(T, "_device_bytes_limit", lambda: 16 << 30)
+    cfg = _one_layer_qwen3()
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda k: T.init_params(k, cfg), jax.random.PRNGKey(0)))
+    toks = jax.ShapeDtypeStruct((8, 512), jnp.int32,
+                                sharding=NamedSharding(mesh, P("data")))
+
+    def grads(p, t):
+        def loss(p):
+            with jax.named_scope("model"):
+                return T.loss_fn(p, cfg, {"tokens": t, "labels": t})
+        return jax.value_and_grad(loss)(p)
+
+    fn = jax.shard_map(grads, mesh=mesh, in_specs=(P(), P("data")),
+                       out_specs=(P(), P()), axis_names=frozenset({"data"}),
+                       check_vma=False)
+    text = jax.jit(fn).lower(params, toks).compile().as_text()
+    calls = _mosaic_op_names(text)
+    assert calls and all("attention" in c.split("/") for c in calls)
+    backward = [c for c in calls if "transpose(jvp(" in c]
+    assert backward and len(backward) < len(calls)
+
+
+def test_prefill_over_tensor_parallel_mesh_keeps_dense_attention(
+        topo, monkeypatch):
+    """Serving prefill is a jit over NamedShardings with the heads sharded
+    over the ``model`` axis, and no mesh its trace can see: attention
+    keeps the dense branch there, so no Mosaic call sits among the
+    partitioned ops."""
+    from repro.kernels import ops as kernel_ops
+    from repro.models import transformer as T
+    from repro.runtime.serve import build_prefill
+
+    monkeypatch.setattr(kernel_ops, "on_tpu", lambda: True)
+    cfg = _one_layer_qwen3()
+    mesh = Mesh(np.array(topo.devices[:2]).reshape(1, 2), ("data", "model"))
+    params = jax.eval_shape(lambda k: T.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 512), jnp.int32)}
+    text = build_prefill(cfg, mesh).lower(params, batch).compile().as_text()
+    assert not _mosaic_op_names(text)
