@@ -2,8 +2,9 @@
 
 Three evidence sources:
   * measured wall-time of the controller-datapath kernels on the functional
-    (interpret) path — the byte-exact reference implementation — staged
-    chain vs the codec-owned fused kernels (repro.kernels.fused);
+    (interpret) path — the byte-exact reference implementation — the
+    distributed vote chain's three kernels vs the one-kernel local
+    pipeline (repro.kernels.fused);
   * modeled per-bucket kernel-launch counts and HBM bytes of the fused vs
     unfused pipelines from each codec's KernelSet accounting (merged into
     BENCH_codecs.json for the nightly fused-vs-unfused gate);
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import kernels as K
+from repro.kernels import fused as KF
 from repro.core.exposure import ExposureModel, TpuDatapathModel, envelope_sweep
 from repro.core.traffic import wire_bytes_per_device
 from repro.core.modes import AggregationMode, Schedule
@@ -63,23 +65,17 @@ def rows():
     stack = jnp.stack([K.pack_signs(jnp.asarray(rng.randn(m, 128),
                                                 jnp.float32))
                        for _ in range(w)])
-    counts = K.popcount_stack(stack)
     gate = K.ternary_gate_words(m)
-    sw, mw = K.majority_decode(counts, num_workers=w, gate_words=gate)
+    sw, mw = KF.vote_combine(stack, gate, num_workers=w)
 
     out.append(("datapath/pack_signs_256KiB", _time(K.pack_signs, plane),
                 f"elements={m*128}"))
-    out.append(("datapath/popcount_w8", _time(K.popcount_stack, stack),
-                "W=8"))
-    out.append(("datapath/majority_decode",
-                _time(lambda c: K.majority_decode(c, num_workers=w,
-                                                  gate_words=gate), counts),
-                "ternary-gated"))
+    out.append(("datapath/vote_combine_w8",
+                _time(lambda s: KF.vote_combine(s, gate, num_workers=w),
+                      stack),
+                "W=8 ternary-gated"))
     out.append(("datapath/unpack_ternary", _time(K.unpack_ternary, sw, mw),
                 ""))
-    out.append(("datapath/apply_sign_update",
-                _time(lambda p: K.apply_sign_update(p, sw, mw, 0.01), plane),
-                "fused"))
 
     # Table 4 analogue: modeled exposure at the production operating point
     n = 8 << 20                      # 8M-element bucket
@@ -130,10 +126,10 @@ def rows():
 def fused_rows():
     """Fused vs unfused datapath: measured wall time + modeled accounting.
 
-    Wall time compares the staged interpret-mode chain (pack -> popcount
-    -> majority -> unpack) against the single fused ``vote_pipeline``
-    kernel on the same payload, and the staged int4 reference against
-    the one-launch two-phase quant kernel.  The modeled section prices
+    Wall time compares the distributed chain's kernels in interpret mode
+    (pack -> vote_combine -> unpack) against the single fused
+    ``vote_pipeline`` kernel on the same payload, and the jitted int4
+    reference against the one-launch two-phase quant kernel.  The modeled section prices
     every registered codec that brings a :class:`KernelSet` — launch
     count and HBM bytes per 8M-element bucket at W=32, fused vs unfused
     — and merges the numbers into ``BENCH_codecs.json`` under each
@@ -149,20 +145,19 @@ def fused_rows():
     stack = jnp.asarray(rng.randn(w, m, 128), jnp.float32)
     gate = fused.local_gate_words(m // ref.PACK, ternary=True)
 
-    def staged(s):
+    def chain(s):
         words = jnp.stack([K.pack_signs(s[i], interpret=True)
                            for i in range(w)])
-        counts = K.popcount_stack(words, interpret=True)
-        sw, mw = K.majority_decode(counts, num_workers=w, gate_words=gate,
-                                   interpret=True)
+        sw, mw = fused.vote_combine(words, gate, num_workers=w,
+                                    interpret=True)
         return K.unpack_ternary(sw, mw, interpret=True)
 
-    t_staged = _time(staged, stack)
+    t_chain = _time(chain, stack)
     t_fused = _time(lambda s: fused.vote_pipeline(
         s, gate, num_workers=w, interpret=True), stack)
-    out.append(("datapath/fused/vote_staged_4op", t_staged, "W=8 interpret"))
+    out.append(("datapath/fused/vote_chain_3op", t_chain, "W=8 interpret"))
     out.append(("datapath/fused/vote_pipeline_1op", t_fused,
-                f"W=8 interpret vs_staged={t_staged / t_fused:.2f}x "
+                f"W=8 interpret vs_chain={t_chain / t_fused:.2f}x "
                 "(interpret-mode wall; the modeled rows are the perf claim)"))
 
     plane = jnp.asarray(rng.randn(m, 128), jnp.float32)

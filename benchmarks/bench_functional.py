@@ -15,8 +15,8 @@ import numpy as np
 
 from repro import kernels as K
 from repro.core import AdmissionPlan, AggregationMode, GroupPolicy
-from repro.fabric import Fabric
-from repro.kernels import ref
+from repro.fabric import Fabric, aggregate_tree
+from repro.kernels import fused as KF, ref
 
 
 def _fabric_session_row():
@@ -50,7 +50,8 @@ def _fused_bucketing_rows():
     (qwen3_0p6b smoke) under the paper's recovered operating point, and
     reports the collective-launch reduction — O(leaves) per-leaf vs
     O(buckets) fused — plus measured host-local dispatch latency and a
-    bit-for-bit cross-check of the two paths.
+    bit-for-bit cross-check against the per-leaf reference
+    (``aggregate_tree``).
     """
     from repro.configs import get_config
     from repro.models import init_params
@@ -68,16 +69,19 @@ def _fused_bucketing_rows():
     grads = jax.tree.map(
         lambda s: jnp.asarray(rng.randn(*s.shape), jnp.float32), params)
 
-    def timed(fused):
-        agg, _ = fabric.aggregate(grads, plan, fused=fused)  # warm caches
+    policies = fabric.resolve(grads, plan)
+
+    def timed(run):
+        agg, _ = run()                                     # warm caches
         jax.block_until_ready(agg)
         t0 = time.perf_counter()
-        agg, _ = fabric.aggregate(grads, plan, fused=fused)
+        agg, _ = run()
         jax.block_until_ready(agg)
         return agg, (time.perf_counter() - t0) * 1e6
 
-    per_leaf, t_leaf = timed(False)
-    fused, t_fused = timed(True)
+    per_leaf, t_leaf = timed(
+        lambda: aggregate_tree(fabric.context, grads, policies))
+    fused, t_fused = timed(lambda: fabric.aggregate(grads, plan))
     exact = all(jax.tree.leaves(jax.tree.map(
         lambda a, b: bool(np.array_equal(np.asarray(a), np.asarray(b))),
         per_leaf, fused)))
@@ -104,8 +108,8 @@ def rows():
 
     t0 = time.perf_counter()
     stack = jnp.stack(words)
-    counts = K.popcount_stack(stack)
-    sw_b, mw_b = K.majority_decode(counts, num_workers=w)
+    ones = KF.local_gate_words(stack.shape[1], ternary=False)
+    sw_b, mw_b = KF.vote_combine(stack, ones, num_workers=w)
     u_bin = ref.from_plane(K.unpack_ternary(sw_b, mw_b), n)
     jax.block_until_ready(u_bin)
     t_bin = (time.perf_counter() - t0) * 1e6
@@ -115,7 +119,7 @@ def rows():
                                 jnp.asarray(grads))))
 
     gate = K.ternary_gate_words(planes[0].shape[0])
-    sw_t, mw_t = K.majority_decode(counts, num_workers=w, gate_words=gate)
+    sw_t, mw_t = KF.vote_combine(stack, gate, num_workers=w)
     u_ter = ref.from_plane(K.unpack_ternary(sw_t, mw_t), n)
     ter_ok = np.array_equal(np.asarray(u_ter),
                             np.asarray(ref.gternary_aggregate_dense(

@@ -51,11 +51,11 @@ def main():
               f"exposed={rep.exposed_pct:.2f}% of step "
               f"({'datapath hidden' if rep.hidden else 'datapath exposed'})")
 
-    # Fused datapath (DESIGN §12): codecs that bring a KernelSet lower
-    # each bucket's encode→vote→decode(+EF) as fused Pallas kernels —
-    # bit-identical to the staged reference path, fewer launches, less
-    # HBM traffic.  layout_kernel_stats prices the exact bucket layout
-    # the train step below will launch.
+    # Fused datapath (DESIGN §12): each bucket's encode→vote→decode(+EF)
+    # runs as fused Pallas kernels — bit-identical to kernels/ref.py,
+    # fewer launches and less HBM traffic than a stage-per-kernel chain
+    # (modeled).  layout_kernel_stats prices the exact bucket layout the
+    # train step below will launch.
     from repro.fabric import layout_kernel_stats
     layout = fabric.layout_for(params, plan)
     stats = layout_kernel_stats(layout, fabric.num_workers)
@@ -63,8 +63,7 @@ def main():
           f"launches fused={stats['launches_fused']} "
           f"vs unfused={stats['launches_unfused']}, HBM/step "
           f"{stats['hbm_bytes_fused'] / 2**20:.0f}MiB fused vs "
-          f"{stats['hbm_bytes_unfused'] / 2**20:.0f}MiB unfused "
-          f"(opt out: Fabric(..., fused_kernels=False))")
+          f"{stats['hbm_bytes_unfused'] / 2**20:.0f}MiB unfused (modeled)")
     assert stats["launches_fused"] < stats["launches_unfused"]
 
     trainer = Trainer(cfg, mesh, AdamW(peak_lr=2e-3, total_steps=200),

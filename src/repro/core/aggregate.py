@@ -1,11 +1,10 @@
-"""Tree-level gradient aggregation under an admission plan (legacy seam).
+"""Error-feedback state and policy trees for tree-level aggregation.
 
-The canonical implementation lives in :mod:`repro.fabric` — a
+Aggregation itself lives in :mod:`repro.fabric` — a
 :class:`~repro.fabric.Fabric` session owns dispatch (via the
 schedule-backend registry), the aggregation context, and EF-state
-handling.  This module keeps the original free-function surface as thin
-deprecation shims plus :func:`init_ef_states`, the worker-local EF
-initializer the session builds on.
+handling.  This module keeps :func:`init_ef_states`, the worker-local EF
+initializer the session builds on, and :func:`make_policy_tree`.
 
 Error-feedback residual state (beyond paper, optional) is carried as a
 pytree matching the params: EF-enabled leaves hold a ``(1, *shape)`` local
@@ -15,14 +14,12 @@ plans (one jit cache entry per plan signature, not per step).
 """
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 from .buckets import AdmissionPlan, GroupRules, resolve_policies
-
-Axes = Sequence[str] | str
 
 
 def init_ef_states(params: Any, policies: Any, dtype=jnp.float32) -> Any:
@@ -32,21 +29,6 @@ def init_ef_states(params: Any, policies: Any, dtype=jnp.float32) -> Any:
             return jnp.zeros((1,) + tuple(p.shape), dtype)
         return jnp.zeros((), dtype)
     return jax.tree.map(make, params, policies, is_leaf=None)
-
-
-def aggregate_gradients(grads: Any, policies: Any, dp_axes: Axes,
-                        num_workers: int, ef_states: Any | None = None,
-                        interpret: bool | None = None):
-    """Deprecated free-function shim — use ``Fabric.aggregate``.
-
-    Aggregates a gradient tree leaf-by-leaf under resolved policies,
-    inside a shard_map whose manual axes are ``dp_axes``.  Returns
-    ``(aggregates, new_ef_states)``.
-    """
-    from ..fabric import AggregationContext, aggregate_tree
-    ctx = AggregationContext(dp_axes=dp_axes, num_workers=num_workers,
-                             interpret=interpret)
-    return aggregate_tree(ctx, grads, policies, ef_states=ef_states)
 
 
 def make_policy_tree(params: Any, plan: AdmissionPlan,

@@ -21,12 +21,13 @@ Two schedules implement the same semantics with different bytes-on-wire:
                       all-reduce); the XLA realization widens the psum
                       operand to int32 so the margin stays exact at any W.
   * ``packed_a2a``  — the controller schedule.  Workers pack sign bits
-                      (``sign_pack`` kernel, N/8 bytes), ``all_to_all``
-                      routes each packed shard to the device that "owns"
-                      that element range (the write into the CXL-resident
-                      buffer), the owner runs the PopCount/majority Pallas
-                      datapath, and the packed ternary result is
-                      all-gathered back (the read response).
+                      (N/8 bytes), ``all_to_all`` routes each packed shard
+                      to the device that "owns" that element range (the
+                      write into the CXL-resident buffer), the owner runs
+                      the PopCount/majority Pallas kernel, and the packed
+                      ternary result is all-gathered back (the read
+                      response); :func:`repro.kernels.fused.
+                      fused_packed_vote` is the datapath.
                       ~(N/8 + N/4) bytes/device: ~21x less than FP32.
 
 FP32 aggregation stays available per bucket (``fp32_allreduce``), exactly
@@ -46,7 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import kernels as K
 from ..kernels import fused as KF
 from ..kernels.ops import fully_manual
 from .modes import AggregationMode, Schedule
@@ -139,75 +139,34 @@ def lowbit_vote_psum(g: jax.Array, dp_axes: Axes, num_workers: int, *,
 def _packed_a2a_local(g: jax.Array, dp_axes: Axes, num_workers: int, *,
                       ternary: bool, gate_phase: int,
                       ef: jax.Array | None, interpret: bool | None,
-                      gate_mask=None, kernels: KF.KernelSet | None = None):
+                      gate_mask=None):
     """Packed aggregation over DP for a *fully local* array.
 
     ``gate_mask`` (host-side boolean (N,) array) overrides the uniform
     flat-index 2-of-3 gate with an arbitrary keep pattern; the fused
     bucket path uses it to carry the concatenation of per-leaf gates.
-    ``kernels`` (a vote-capable :class:`~repro.kernels.fused.KernelSet`)
-    reroutes the whole chain to the codec's fused kernels — bit-identical
-    by the KernelSet contract, fewer launches and no intermediate HBM
-    materialization.
+    The one call site of the packed datapath, so a fault that drops the
+    exchange (the benchmark's ``no_exchange``) replaces this function.
     """
-    if kernels is not None and kernels.votes:
-        return kernels.packed_vote(g, dp_axes, num_workers, ternary=ternary,
-                                   gate_phase=gate_phase, ef=ef,
-                                   interpret=interpret, gate_mask=gate_mask)
-    w = num_workers
-    n = g.size
-    with jax.named_scope("encode"):
-        g_eff, ef = _ef_inject(g, ef)
-        plane = K.to_plane(g_eff.reshape(-1))
-        words = K.pack_signs(plane, interpret=interpret)  # (R, 128) u32
-        r = words.shape[0]
-        rw = K.shard_rows(r, w)
-        pad_r = rw * w - r
-        if pad_r:
-            words = jnp.pad(words, ((0, pad_r), (0, 0)))
-    # "write-side materialization": route worker payloads to the owning
-    # aggregator for each element range.
-    with jax.named_scope("exchange"):
-        routed = jax.lax.all_to_all(words.reshape(w, rw, K.LANE), dp_axes,
-                                    split_axis=0, concat_axis=0, tiled=False)
-    # "controller datapath": PopCount across workers + majority/ternary gate
-    # (the gate helper is shared with the fused driver, so both pipelines
-    # consume byte-identical zero gates by construction).
-    with jax.named_scope("vote"):
-        counts = K.popcount_stack(routed, interpret=interpret)
-        gate = KF.shard_gate_words(dp_axes, rw, ternary=ternary,
-                                   gate_phase=gate_phase, gate_mask=gate_mask,
-                                   total_rows=r + pad_r)
-        sw, mw = K.majority_decode(counts, num_workers=w, gate_words=gate,
-                                   interpret=interpret)
-    # "read response": packed ternary aggregate gathered back to all workers.
-    with jax.named_scope("exchange"):
-        sw_all = jax.lax.all_gather(sw, dp_axes, axis=0, tiled=True)[:r]
-        mw_all = jax.lax.all_gather(mw, dp_axes, axis=0, tiled=True)[:r]
-    with jax.named_scope("decode"):
-        u_plane = K.unpack_ternary(sw_all, mw_all, dtype=jnp.float32,
-                                   interpret=interpret)
-        u = K.from_plane(u_plane, n).reshape(g.shape).astype(g.dtype)
-    with jax.named_scope("encode"):      # the residual is the encoder's state
-        return u, _ef_update(g_eff, ef)
+    return KF.fused_packed_vote(g, dp_axes, num_workers, ternary=ternary,
+                                gate_phase=gate_phase, ef=ef,
+                                interpret=interpret, gate_mask=gate_mask)
 
 
 def lowbit_packed_a2a(g: jax.Array, dp_axes: Axes, num_workers: int, *,
                       model_spec: P | None = None, ternary: bool = False,
                       gate_phase: int = 0, ef: jax.Array | None = None,
-                      interpret: bool | None = None, gate_mask=None,
-                      kernels: KF.KernelSet | None = None):
+                      interpret: bool | None = None, gate_mask=None):
     """Controller-schedule aggregation.
 
     The datapath runs under :func:`fully_manual`.  If the leaf is sharded
     over auto (tensor-parallel) mesh axes, ``model_spec`` must give its
     PartitionSpec, and each shard votes on its own slice.  ``gate_mask``
     (fully local payloads only) overrides the flat-index ternary gate —
-    see :func:`_packed_a2a_local`.  ``kernels`` routes the chain to the
-    codec's fused kernel set when present.
+    see :func:`_packed_a2a_local`.
     """
     kwargs = dict(ternary=ternary, gate_phase=gate_phase, interpret=interpret,
-                  kernels=kernels, gate_mask=gate_mask)
+                  gate_mask=gate_mask)
     spec = P() if model_spec is None else model_spec
     assert gate_mask is None or all(a is None for a in spec), \
         "gate_mask requires a fully local payload"
@@ -248,7 +207,7 @@ def sign_of_mean(g: jax.Array, dp_axes: Axes) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# per-leaf dispatch
+# per-leaf policy
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -266,20 +225,3 @@ class LeafPolicy:
     model_spec: Any = None          # PartitionSpec over auto (TP) axes
     gate_phase: int = 0
     error_feedback: bool = False
-
-
-def aggregate_leaf(g: jax.Array, policy: LeafPolicy, dp_axes: Axes,
-                   num_workers: int, ef: jax.Array | None = None,
-                   interpret: bool | None = None):
-    """Deprecated free-function shim — use ``repro.fabric``.
-
-    Dispatches through the schedule-backend registry (no hardcoded
-    mode/schedule branching lives here anymore).  Returns
-    ``(aggregate, new_ef)``; for FP32 the aggregate is the mean gradient,
-    for low-bit modes it is the ternary direction in {-1, 0, +1}.
-    """
-    from ..fabric import AggregationContext
-    from ..fabric.session import aggregate_leaf as _dispatch
-    ctx = AggregationContext(dp_axes=dp_axes, num_workers=num_workers,
-                             interpret=interpret)
-    return _dispatch(ctx, g, policy, ef=ef)

@@ -43,7 +43,7 @@ from ..core import AdmissionPlan, GroupRules, plan_traffic_ratio
 from ..core.diagnostics import group_cosines_from_mean
 from ..fabric import Fabric, TrainState
 from ..fabric.control import Telemetry, make_controller
-from ..fabric.session import aggregate_tree, aggregate_tree_bucketed
+from ..fabric.session import aggregate_tree_bucketed
 from ..models import ModelConfig, init_params
 from ..models import loss_fn as model_loss_fn
 from ..optim import Optimizer
@@ -76,7 +76,6 @@ class ElasticConfig:
     #: times fully deterministic (nominal seconds scaled by the active
     #: fault models) for reproducible detector/controller runs.
     synthetic_step_time_s: float | None = None
-    fused: bool = True
 
 
 def _worker_stream(data: Any, worker: int):
@@ -145,7 +144,7 @@ class ElasticTrainer:
         self.seed = seed
         self.fabric = Fabric(dp_axes=("w",),
                              num_workers=self.membership.view.num_workers,
-                             rules=rules, fused=self.ecfg.fused)
+                             rules=rules)
         self.fabric.bind_membership(self.membership.view)
         if controller is not None:
             self.fabric.attach_controller(controller)
@@ -228,19 +227,13 @@ class ElasticTrainer:
         policies = fabric.resolve(params_like, plan)
         groups = fabric.groups(params_like)
         ctx = fabric.context
-        use_fused = fabric.fused
-        layout = (fabric.layout_for(params_like, policies)
-                  if use_fused else None)
+        layout = fabric.layout_for(params_like, policies)
         lf = self.loss or (lambda p, b: model_loss_fn(p, cfg, b))
 
         def per_worker(params, batch, ef):
             lval, grads = jax.value_and_grad(lf)(params, batch)
-            if use_fused:
-                agg, new_ef = aggregate_tree_bucketed(
-                    ctx, grads, policies, ef_states=ef, layout=layout)
-            else:
-                agg, new_ef = aggregate_tree(ctx, grads, policies,
-                                             ef_states=ef)
+            agg, new_ef = aggregate_tree_bucketed(
+                ctx, grads, policies, ef_states=ef, layout=layout)
             return jax.lax.pmean(lval, "w"), agg, new_ef
 
         def step_fn(state: TrainState, batch):

@@ -36,15 +36,8 @@ from .codecs import get_codec, resolve_leaf_gate_mask, ring_wire_bytes
 from .registry import AggregationContext, get_schedule, register_schedule
 
 
-def _codec_kernels(ctx: AggregationContext, codec):
-    """The codec's fused kernel set, honoring the session opt-out.
-
-    Returns None when the session pinned the staged path
-    (``fused_kernels=False``) or the codec brings no kernels — both
-    bit-identical to the fused realization by the KernelSet contract.
-    """
-    if not getattr(ctx, "fused_kernels", True):
-        return None
+def _codec_kernels(codec):
+    """The codec's fused kernel set, None when it brings none."""
     hook = getattr(codec, "pallas_kernels", None)
     return None if hook is None else hook()
 
@@ -76,7 +69,7 @@ class Fp32AllreduceBackend:
 
     def aggregate(self, ctx: AggregationContext, g, policy, ef=None):
         codec = get_codec(policy.mode)
-        ks = _codec_kernels(ctx, codec)
+        ks = _codec_kernels(codec)
         if ks is not None and ks.means:
             # fused encode kernel on the flat payload (bit-identical to
             # codec.encode — the KernelSet contract), decode on the mean
@@ -88,7 +81,7 @@ class Fp32AllreduceBackend:
 
     def aggregate_flat(self, ctx: AggregationContext, flat, codec, *,
                        gate=None):
-        ks = _codec_kernels(ctx, codec)
+        ks = _codec_kernels(codec)
         if ks is not None and ks.means:
             return codec.decode(ctx, _mean_kernel_path(ctx, ks, flat))
         return codec.decode(ctx, fp32_allreduce(codec.encode(ctx, flat),
@@ -114,8 +107,7 @@ class VotePsumBackend:
     This transport deliberately ignores codec kernel sets: its dense
     int8 votes have no packed word-plane representation to fuse — the
     psum *is* the combine, and XLA already fuses the elementwise
-    vote/majority stages around it.  (``fused_kernels`` is therefore a
-    no-op here, trivially bit-identical.)
+    vote/majority stages around it.
     """
 
     name = "vote_psum"
@@ -155,7 +147,12 @@ class VotePsumBackend:
 
 @register_schedule(Schedule.PACKED_A2A)
 class PackedA2ABackend:
-    """The controller schedule: pack -> all_to_all -> PopCount -> gather."""
+    """The controller schedule: pack -> all_to_all -> PopCount -> gather.
+
+    Every vote codec runs the same fused kernel chain
+    (:func:`repro.kernels.fused.fused_packed_vote`): codecs differ only
+    in their gate words, which are a kernel operand.
+    """
 
     name = "packed_a2a"
     fusable = True
@@ -163,8 +160,7 @@ class PackedA2ABackend:
 
     def aggregate(self, ctx: AggregationContext, g, policy, ef=None):
         codec = get_codec(policy.mode)
-        ks = _codec_kernels(ctx, codec)
-        # a custom leaf gate packs into gate words exactly like the fused
+        # a custom leaf gate packs into gate words exactly like the bucket
         # path, so both vote transports zero the same elements (the
         # packed path needs a fully local payload for gate masks)
         return lowbit_packed_a2a(
@@ -173,20 +169,16 @@ class PackedA2ABackend:
             ternary=codec.gated, gate_phase=policy.gate_phase,
             gate_mask=resolve_leaf_gate_mask(codec, g.shape,
                                              policy.gate_phase),
-            ef=ef, interpret=ctx.interpret,
-            kernels=ks if ks is not None and ks.votes else None)
+            ef=ef, interpret=ctx.interpret)
 
     def aggregate_flat(self, ctx: AggregationContext, flat, codec, *,
                        gate=None):
         # the packed schedule needs the host mask to pack gate words
         # (1 bit/element once packed — see gate_words_from_mask)
         mask = None if gate is None else gate.mask()
-        ks = _codec_kernels(ctx, codec)
         u, _ = lowbit_packed_a2a(flat, ctx.dp_axes, ctx.num_workers,
                                  ternary=codec.gated, gate_mask=mask,
-                                 interpret=ctx.interpret,
-                                 kernels=ks if ks is not None and ks.votes
-                                 else None)
+                                 interpret=ctx.interpret)
         return u
 
     def wire_bytes_per_device(self, n_elements: int, mode, num_workers: int,
@@ -252,10 +244,9 @@ class HierarchicalBackend:
     and flat-backend EF all stay bit-identical.
 
     Fused kernels resolve *per hop*: each leg dispatches through its hop
-    codec's own transport with a context that preserves the session's
-    ``fused_kernels`` flag (``dataclasses.replace`` below), so e.g. a
-    packed gbinary backbone hop runs the fused vote chain while the
-    intra-node fp32 hop stays on plain psum — no extra wiring here.
+    codec's own transport, so e.g. a packed gbinary backbone hop runs the
+    fused vote chain while the intra-node fp32 hop stays on plain psum —
+    no extra wiring here.
     """
 
     name = "hierarchical"

@@ -182,8 +182,8 @@ class GradientCodec:
                            sim's flit pipeline.
     ``default_schedule`` — transport used when a plan names no schedule.
     ``pallas_kernels``   — optional fused Pallas :class:`~repro.kernels.
-                           fused.KernelSet`; transports consult it when
-                           the session runs with ``fused_kernels=True``.
+                           fused.KernelSet`; the psum transport runs a
+                           mean-capable set around its collective.
     """
 
     name: str = "identity"
@@ -257,15 +257,16 @@ class GradientCodec:
     def pallas_kernels(self):
         """The codec's fused :class:`~repro.kernels.fused.KernelSet`.
 
-        ``None`` (the default) keeps the staged / reference-jnp path.
-        Vote codecs return a vote-capable set (the ``packed_a2a``
-        transport hands it the whole bucket); mean codecs return a
+        ``None`` (the default) keeps the codec's own jnp
+        :meth:`encode`/:meth:`decode`.  Mean codecs return a
         mean-capable set (the psum transport runs its
-        ``encode_flat``/``decode_apply`` around the collective).  The
-        returned set must be bit-identical to the codec's
-        :meth:`encode`/:meth:`decode` + the staged kernels wherever both
-        run — sessions key compiled steps on :meth:`kernel_signature`,
-        not object identity, so return a stable (cached) instance.
+        ``encode_flat``/``decode_apply`` around the collective), which
+        must be bit-identical to the codec's :meth:`encode`/:meth:`decode`.
+        Vote codecs may return a vote-capable set for the modeled
+        launch/HBM accounting; the ``packed_a2a`` transport runs the one
+        fused vote chain for every vote codec either way.  Sessions key
+        compiled steps on :meth:`kernel_signature`, not object identity,
+        so return a stable (cached) instance.
         """
         return None
 

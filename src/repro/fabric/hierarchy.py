@@ -188,7 +188,7 @@ class HierarchicalCodec(GradientCodec):
     def pallas_kernels(self):
         """None: a multi-hop route has no single kernel set — each hop
         leg resolves its own codec's kernels inside the hierarchical
-        backend (the hop context preserves ``fused_kernels``)."""
+        backend."""
         return None
 
     def kernel_signature(self) -> str | None:

@@ -42,17 +42,12 @@ class AggregationContext:
     ``num_workers`` — product of the dp-axis sizes (the paper's W);
     ``interpret``   — Pallas interpret-mode override for kernel backends;
     ``mesh``        — the owning mesh, when a backend needs topology
-                      (None for host-local / virtual-worker use);
-    ``fused_kernels`` — consult codecs' fused ``pallas_kernels()`` sets
-                      (the session's ``fused_kernels=False`` opt-out
-                      pins the staged pipeline; results are
-                      bit-identical either way).
+                      (None for host-local / virtual-worker use).
     """
     dp_axes: Any = ()
     num_workers: int = 1
     interpret: bool | None = None
     mesh: Any = None
-    fused_kernels: bool = True
 
 
 @runtime_checkable

@@ -4,9 +4,9 @@ A :class:`Fabric` is constructed once from ``(mesh, dp_axes, rules,
 interpret)`` and owns everything the old free-function API made every
 caller re-thread by hand: the worker count, group assignment, policy
 resolution, error-feedback state init/specs, schedule dispatch (via the
-backend registry — by default through fused 32 MiB *buckets*, one
-collective per bucket instead of one per leaf; see
-:func:`aggregate_tree_bucketed`), and the per-plan-signature jit cache
+backend registry, through 32 MiB *buckets*: one collective per bucket
+instead of one per leaf; see :func:`aggregate_tree_bucketed`), and the
+per-plan-signature jit cache
 for compiled train steps.  It is the seam later scaling work (new
 collectives, async overlap, multi-backend) plugs into — swap or add a
 registered :class:`~repro.fabric.registry.ScheduleBackend` and every
@@ -74,7 +74,11 @@ def aggregate_tree(ctx: AggregationContext, grads: Any, policies: Any,
                    ef_states: Any | None = None):
     """Aggregate a gradient pytree leaf-by-leaf under resolved policies.
 
-    Runs inside a shard_map whose manual axes are ``ctx.dp_axes``.
+    The per-leaf reference that :func:`aggregate_tree_bucketed` must
+    match bit for bit; no production path calls it (the session always
+    aggregates through its bucket layout, whose per-leaf fallback is
+    :func:`aggregate_leaf`).  Runs inside a shard_map whose manual axes
+    are ``ctx.dp_axes``.
     Error-feedback leaves hold a ``(1, *shape)`` local residual (globally
     ``(W, *shape)`` sharded over the DP axes); disabled leaves hold a
     scalar sentinel so the tree structure stays static across plans.
@@ -290,11 +294,8 @@ class TrainState:
 
 
 class CompiledStep(NamedTuple):
-    """One compiled train step and its I/O contracts.
-
-    Tuple-compatible with the legacy ``build_train_step`` return value
-    ``(jitted, state_shardings, batch_sharding, aux)``.
-    """
+    """One compiled train step and its I/O contracts: unpacks as
+    ``(jitted, state_shardings, batch_sharding, aux)``."""
     step_fn: Callable
     state_shardings: Any
     batch_sharding: Any
@@ -373,9 +374,7 @@ class Fabric:
                  rules: GroupRules | None = None,
                  interpret: bool | None = None,
                  num_workers: int | None = None,
-                 bucket_bytes: int = DEFAULT_BUCKET_BYTES,
-                 fused: bool = True,
-                 fused_kernels: bool = True):
+                 bucket_bytes: int = DEFAULT_BUCKET_BYTES):
         self.mesh = mesh
         if dp_axes is None:
             dp_axes = ("data",) if mesh is not None else ()
@@ -390,13 +389,6 @@ class Fabric:
         else:
             self.num_workers = 1
         self.bucket_bytes = int(bucket_bytes)
-        self.fused = bool(fused)
-        # fused_kernels routes codec payloads through their registered
-        # Pallas KernelSet (repro.kernels.fused) — one kernel per bucket
-        # for encode -> vote/reduce -> decode(+EF) instead of the staged
-        # four-op chain.  Bit-identical either way; False pins the
-        # staged pipeline (debugging / A-B validation).
-        self.fused_kernels = bool(fused_kernels)
         self.membership_epoch = 0        # bumped by bind_membership
         self.controller = None           # attached admission controller
         self._compiled: dict[tuple, CompiledStep] = {}
@@ -453,8 +445,7 @@ class Fabric:
     def context(self) -> AggregationContext:
         return AggregationContext(dp_axes=self.dp_axes,
                                   num_workers=self.num_workers,
-                                  interpret=self.interpret, mesh=self.mesh,
-                                  fused_kernels=self.fused_kernels)
+                                  interpret=self.interpret, mesh=self.mesh)
 
     def resolve(self, params_like: Any, plan: AdmissionPlan,
                 pspecs: Any | None = None) -> Any:
@@ -533,8 +524,7 @@ class Fabric:
         return self._layouts[key]
 
     def aggregate(self, grads: Any, plan: AdmissionPlan | Any,
-                  ef: Any | None = None, *, pspecs: Any | None = None,
-                  fused: bool | None = None):
+                  ef: Any | None = None, *, pspecs: Any | None = None):
         """Aggregate a gradient pytree under a plan (or resolved policies).
 
         Runs inside a shard_map whose manual axes are this session's
@@ -543,22 +533,18 @@ class Fabric:
         may be an :class:`AdmissionPlan` (resolved against ``grads`` with
         this session's rules) or an already-resolved LeafPolicy pytree.
 
-        By default (``fused=None`` -> the session's ``fused`` flag, True
-        unless overridden) compatible leaves are fused into flat
-        ``bucket_bytes`` buckets and aggregated by one collective per
-        bucket — bit-identical to the per-leaf path (``fused=False``)
-        for every built-in schedule.  Returns ``(aggregates, new_ef)``.
+        Compatible leaves are fused into flat ``bucket_bytes`` buckets
+        and aggregated by one collective per bucket — bit-identical to
+        the per-leaf reference :func:`aggregate_tree` for every built-in
+        schedule.  Returns ``(aggregates, new_ef)``.
         """
         if isinstance(plan, AdmissionPlan):
             policies = self.resolve(grads, plan, pspecs=pspecs)
         else:
             policies = plan
-        use_fused = self.fused if fused is None else fused
-        if use_fused:
-            layout = self.layout_for(grads, policies)
-            return aggregate_tree_bucketed(self.context, grads, policies,
-                                           ef_states=ef, layout=layout)
-        return aggregate_tree(self.context, grads, policies, ef_states=ef)
+        layout = self.layout_for(grads, policies)
+        return aggregate_tree_bucketed(self.context, grads, policies,
+                                       ef_states=ef, layout=layout)
 
     # -- simulation -----------------------------------------------------
 
@@ -630,18 +616,17 @@ class Fabric:
                    loss: Callable | None = None,
                    zero1: bool = True,
                    grad_accum: int = 1,
-                   donate: bool = True,
-                   fused: bool | None = None) -> CompiledStep:
+                   donate: bool = True) -> CompiledStep:
         """Compile one train step for a given admission plan.
 
         ``params_like``: a concrete or abstract (ShapeDtypeStruct) params
         tree — used only for structure/paths.  ``grad_accum`` splits the
         per-device batch into that many sequentially-scanned microbatches
         (activation memory / grad_accum, one aggregation per step —
-        communication volume unchanged, overlap-friendly).  ``fused``
-        (default: the session's flag) routes aggregation through the
-        bucket layout — one collective per 32 MiB bucket; the layout is
-        planned here once and cached with the compiled step.
+        communication volume unchanged, overlap-friendly).  Aggregation
+        runs through the bucket layout — one collective per 32 MiB
+        bucket; the layout is planned here once and cached with the
+        compiled step.
         """
         if self.mesh is None:
             raise ValueError("Fabric.build_step needs a mesh-bound session "
@@ -665,9 +650,7 @@ class Fabric:
             with jax.named_scope("model"):
                 return base_loss(p, b)
 
-        use_fused = self.fused if fused is None else fused
-        layout = (self.layout_for(params_like, policies)
-                  if use_fused else None)
+        layout = self.layout_for(params_like, policies)
 
         @functools.partial(
             jax.shard_map, mesh=mesh,
@@ -694,12 +677,8 @@ class Fabric:
             else:
                 lval, grads = jax.value_and_grad(lf)(params, batch)
             with jax.named_scope("fabric.aggregate"):
-                if use_fused:
-                    agg, new_ef = aggregate_tree_bucketed(
-                        ctx, grads, policies, ef_states=ef, layout=layout)
-                else:
-                    agg, new_ef = aggregate_tree(ctx, grads, policies,
-                                                 ef_states=ef)
+                agg, new_ef = aggregate_tree_bucketed(
+                    ctx, grads, policies, ef_states=ef, layout=layout)
             lval = jax.lax.pmean(lval, dp)
             return lval, agg, new_ef
 
@@ -739,8 +718,7 @@ class Fabric:
             donate_argnums=(0,) if donate else ())
         aux = {"policies": policies, "groups": groups, "num_workers": w,
                "ef_specs": ef_specs, "pspecs": pspecs, "layout": layout,
-               "num_launches": (layout.num_launches if layout is not None
-                                else len(jax.tree.leaves(params_like)))}
+               "num_launches": layout.num_launches}
         return CompiledStep(jitted, state_shardings, batch_sharding, aux)
 
     # -- per-plan-signature jit cache -----------------------------------
@@ -750,8 +728,7 @@ class Fabric:
                  with_diagnostics: bool = False,
                  loss: Callable | None = None,
                  zero1: bool = True,
-                 grad_accum: int = 1,
-                 fused: bool | None = None) -> CompiledStep:
+                 grad_accum: int = 1) -> CompiledStep:
         """Cached :meth:`build_step` — one compiled step per plan
         signature (the XLA analogue of the controller mode latch).
 
@@ -759,25 +736,24 @@ class Fabric:
         frozen dataclasses / callables), so several Trainers may safely
         share one session without cross-model cache hits.
         """
-        use_fused = self.fused if fused is None else fused
         # num_workers + membership epoch: a step compiled for one worker
         # view must never be served after an elastic re-plan, even when
         # the rejoined view happens to have the same worker count
-        # fused_kernels + the plan modes' kernel signatures: a step
-        # compiled against one kernel set must never be served after a
-        # codec (or its kernels) is swapped under the same name
+        # the plan modes' kernel signatures: a step compiled against one
+        # kernel set must never be served after a codec (or its kernels)
+        # is swapped under the same name
         kern_sig = tuple(sorted(
             (codec_name(m), _codec_kernel_sig(m)) for m in plan_modes(plan)))
         key = (plan.signature(), with_diagnostics, zero1, grad_accum,
-               cfg, optimizer, loss, use_fused, self.fused_kernels,
-               kern_sig, self.num_workers, self.membership_epoch)
+               cfg, optimizer, loss, kern_sig, self.num_workers,
+               self.membership_epoch)
         if key not in self._compiled:
             # a miss inside a training run shows in its trace at that step
             with jax.profiler.TraceAnnotation("fabric.build_step"):
                 self._compiled[key] = self.build_step(
                     cfg, optimizer, plan, params_like,
                     with_diagnostics=with_diagnostics, loss=loss,
-                    zero1=zero1, grad_accum=grad_accum, fused=use_fused)
+                    zero1=zero1, grad_accum=grad_accum)
         return self._compiled[key]
 
     def clear_cache(self) -> None:

@@ -1,35 +1,32 @@
 """Codec-owned fused Pallas kernels: one kernel per bucket stage chain.
 
-The staged controller datapath (``sign_pack`` -> ``popcount_stack`` ->
-``majority_decode`` -> ``unpack_ternary``) pays an HBM round-trip between
-every stage — exactly the overhead the paper's five-cycle near-memory
-pipeline exists to avoid.  This module makes fused kernels a *codec
-capability*:
+The paper's five-cycle near-memory pipeline never writes a stage's
+output back to memory between stages; the kernels here keep the vote's
+(M, LANE) int32 counts and the decoded planes in VMEM the same way.
+Fused kernels are a *codec capability*:
 
   * :class:`KernelSet` — the protocol a codec's ``pallas_kernels()`` hook
-    returns: ``encode_flat`` / ``combine`` / ``decode_apply`` entry points
-    plus an optional fused error-feedback residual update, and modeled
-    launch/HBM accounting so benchmarks price fused vs unfused uniformly.
+    returns: ``encode_flat`` / ``decode_apply`` entry points around a
+    mean collective, plus modeled launch/HBM accounting so benchmarks
+    price every codec uniformly.
   * :class:`VoteKernelSet` — the sign-vote chain shared by ``gbinary`` and
     ``gternary``: fused EF-inject+pack encode, a single popcount+majority
-    combine (the staged pipeline's (M, LANE) int32 counts plane never
-    touches HBM), and — when no collective separates the stages — the
-    whole encode -> vote -> decode chain as ONE kernel
+    combine (:func:`vote_combine`), and — when no collective separates
+    the stages — the whole encode -> vote -> decode chain as ONE kernel
     (:func:`vote_pipeline`).
   * :class:`Int4KernelSet` / :class:`TopKKernelSet` — real Pallas kernels
     for the extension codecs (absmax fake-quant as a single two-phase
     kernel; magnitude-threshold sparsify), registered purely through the
     public ``Codec.pallas_kernels`` seam.
-  * :func:`fused_packed_vote` — the bucket-level fusion driver: the
-    ``packed_a2a`` schedule realized with the fused kernels (3 launches
-    distributed, 1 launch when the payload is host-local).
+  * :func:`fused_packed_vote` — the ``packed_a2a`` schedule's datapath
+    for every vote codec (3 launches distributed, 1 launch when the
+    payload is host-local); the vote does not depend on the codec beyond
+    its gate words, which are a kernel operand.
 
 Bit-identity contract: every fused kernel reproduces, bit-for-bit, the
 pure-jnp reference composition in :mod:`repro.kernels.ref`
 (``vote_combine`` / ``vote_pipeline_dense`` / ``encode_pack_ef`` /
-``ef_residual`` / ``int4_quant_plane`` / ``threshold_mask_plane``), which
-are themselves compositions of the staged references — so fused == ref
-transitively proves fused == the unfused pipeline wherever both run.
+``ef_residual`` / ``int4_quant_plane`` / ``threshold_mask_plane``).
 The same three-way dispatch as :mod:`repro.kernels.ops` applies:
 ``interpret=True`` runs the kernel bodies on CPU, ``interpret=None``
 off-TPU takes the reference path (identical bits, clean HLO).
@@ -45,14 +42,12 @@ from jax.experimental import pallas as pl
 from . import ref
 from .ref import LANE, PACK
 from .apply_update import decode_ternary
-from .popcount_majority import count_votes, decode_margin
-from .sign_pack import pack_rows, pick_word_block, positive
+from .sign_pack import pack_rows, pick_word_block, positive, unpack_rows
 from .ops import _mode, pack_signs, unpack_ternary
 
 
 # ---------------------------------------------------------------------------
-# gate-word helpers (shared by the fused AND unfused packed paths, so the
-# two pipelines consume byte-identical zero gates by construction)
+# gate-word helpers
 # ---------------------------------------------------------------------------
 
 def local_gate_words(num_words: int, *, ternary: bool, gate_phase: int = 0,
@@ -95,10 +90,30 @@ def shard_gate_words(dp_axes, rows_per_shard: int, *, ternary: bool,
 # fused kernel bodies
 # ---------------------------------------------------------------------------
 
+def count_votes(packed_ref) -> jax.Array:
+    """(W, RB, LANE) packed sign words -> (32 RB, LANE) int32 vote counts,
+    one worker's word block at a time.  Counts are int32, so any
+    worker-group width W fits."""
+    def add(w, acc):
+        return acc + unpack_rows(packed_ref[w])
+    _, rb, lane = packed_ref.shape
+    return jax.lax.fori_loop(0, packed_ref.shape[0], add,
+                             jnp.zeros((rb * PACK, lane), jnp.int32))
+
+
+def decode_margin(counts: jax.Array, gate: jax.Array, num_workers: int):
+    """(32 RB, LANE) int32 counts + (RB, LANE) gate -> (sign, mask) words.
+
+    mask bit = (2c != W) AND gate bit; sign bit = (2c > W)."""
+    a = 2 * counts - num_workers                                    # vote margin
+    sign = pack_rows((a > 0).astype(jnp.int32))
+    mask = pack_rows((a != 0).astype(jnp.int32))
+    return sign, mask & gate
+
+
 def _encode_pack_ef_kernel(g_ref, e_ref, words_ref, geff_ref):
     """EF inject + sign pack fused: the g_eff = g + e plane is packed the
-    moment it is formed, so the unfused path's inject-pass write/re-read
-    of g_eff before packing never happens."""
+    moment it is formed, never written and re-read before packing."""
     x = g_ref[...] + e_ref[...]
     geff_ref[...] = x
     words_ref[...] = pack_rows(positive(x))
@@ -228,8 +243,9 @@ def vote_combine(routed: jax.Array, gate_words: jax.Array, *,
                  num_workers: int, interpret: bool | None = None):
     """(W, R, LANE) routed sign words + packed gate -> ternary packed pair.
 
-    One kernel for popcount_stack + majority_decode; the int32 counts
-    plane (8x the packed payload) never reaches HBM.
+    PopCount across workers and the majority/ternary decode in one
+    kernel; the int32 counts plane (8x the packed payload) never reaches
+    HBM.
     """
     m = _mode(interpret)
     if m == "ref":
@@ -364,9 +380,10 @@ def ef_update_fused(g_eff: jax.Array, ef: jax.Array, *,
                     interpret: bool | None = None) -> jax.Array:
     """Fused-kernel EF residual update, bit-identical to ``_ef_update``.
 
-    beta is the mean |g_eff| over the *leaf-shaped* array (identical to
-    the unfused reduction); the elementwise residual runs as one kernel
-    on the canonical plane — same per-element ops, so identical bits.
+    beta is the mean |g_eff| over the *leaf-shaped* array (the same
+    reduction as ``_ef_update``); the elementwise residual runs as one
+    kernel on the canonical plane — same per-element ops, so identical
+    bits.
     """
     beta = jnp.mean(jnp.abs(g_eff))
     plane = ref.to_plane(g_eff.reshape(-1))
@@ -375,7 +392,7 @@ def ef_update_fused(g_eff: jax.Array, ef: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
-# bucket-level fusion driver: packed_a2a on the fused kernels
+# bucket level: the packed_a2a vote datapath
 # ---------------------------------------------------------------------------
 
 def fused_packed_vote(g: jax.Array, dp_axes, num_workers: int, *,
@@ -384,14 +401,14 @@ def fused_packed_vote(g: jax.Array, dp_axes, num_workers: int, *,
                       interpret: bool | None = None, gate_mask=None):
     """The ``packed_a2a`` vote schedule realized with fused kernels.
 
-    Distributed (3 launches vs the staged pipeline's 4): fused
-    EF-inject+pack encode -> all_to_all -> fused popcount+majority
-    combine -> all_gather -> decode.  Host-local (``dp_axes`` empty, a
-    configuration the staged path cannot run at all): the entire chain
-    is ONE :func:`vote_pipeline` launch.  Bit-identical to
-    ``core.lowbit._packed_a2a_local`` wherever that path runs.
+    Distributed (3 launches): fused EF-inject+pack encode -> all_to_all
+    -> fused popcount+majority combine -> all_gather -> decode.
+    Host-local (``dp_axes`` empty): the entire chain is ONE
+    :func:`vote_pipeline` launch.  Bit-identical to the dense oracles
+    :func:`repro.kernels.ref.gbinary_aggregate_dense` /
+    ``gternary_aggregate_dense`` of ``g + ef``.
 
-    Returns ``(u, new_ef)`` exactly like the unfused collective.
+    Returns ``(u, new_ef)``; ``new_ef`` is None when ``ef`` is.
     """
     w = num_workers
     n = g.size
@@ -468,9 +485,9 @@ class KernelSet:
     A codec's ``pallas_kernels()`` hook returns one of these (or None to
     keep the reference-jnp path).  Two capability axes:
 
-      * ``votes`` — the set realizes the packed sign-vote chain; the
-        ``packed_a2a`` backend calls :meth:`packed_vote` for the whole
-        bucket.
+      * ``votes`` — the set prices the packed sign-vote chain, which the
+        ``packed_a2a`` backend runs for every vote codec
+        (:func:`fused_packed_vote`).
       * ``means`` — the set realizes encode/decode around a mean
         collective; the psum backend calls :meth:`encode_flat` /
         :meth:`decode_apply` on the flat payload.
@@ -506,11 +523,6 @@ class KernelSet:
                      interpret: bool | None = None) -> jax.Array:
         return payload
 
-    # --- vote-reduction entry point (votes=True sets) ---
-    def packed_vote(self, g, dp_axes, num_workers, *, ternary, gate_phase,
-                    ef, interpret, gate_mask=None):
-        raise NotImplementedError
-
 
 class VoteKernelSet(KernelSet):
     """Fused sign-vote chain for ``gbinary`` / ``gternary``."""
@@ -519,12 +531,6 @@ class VoteKernelSet(KernelSet):
 
     def signature(self) -> str:
         return "vote:v1"
-
-    def packed_vote(self, g, dp_axes, num_workers, *, ternary, gate_phase,
-                    ef, interpret, gate_mask=None):
-        return fused_packed_vote(g, dp_axes, num_workers, ternary=ternary,
-                                 gate_phase=gate_phase, ef=ef,
-                                 interpret=interpret, gate_mask=gate_mask)
 
     def launches(self, *, fused: bool, distributed: bool = True,
                  ef: bool = False) -> int:

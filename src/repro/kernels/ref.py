@@ -186,25 +186,12 @@ def unpack_ternary(sign_words: jax.Array, mask_words: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# fused apply: param update from packed aggregate
-# ---------------------------------------------------------------------------
-
-def apply_sign_update(param_plane: jax.Array, sign_words: jax.Array,
-                      mask_words: jax.Array, scale) -> jax.Array:
-    """param - scale * u, with u decoded from the ternary packed pair."""
-    u = unpack_ternary(sign_words, mask_words, dtype=jnp.float32)
-    out = param_plane.astype(jnp.float32) - jnp.asarray(scale, jnp.float32) * u
-    return out.astype(param_plane.dtype)
-
-
-# ---------------------------------------------------------------------------
 # fused-stage references (oracles for repro.kernels.fused)
 # ---------------------------------------------------------------------------
 #
 # Each function below is the pure-jnp composition the corresponding fused
-# Pallas kernel must reproduce bit-for-bit.  They are deliberately written
-# as compositions of the staged references above wherever one exists, so
-# "fused == ref" transitively proves "fused == staged pipeline".
+# Pallas kernel must reproduce bit-for-bit, written as compositions of
+# the per-stage references above wherever one exists.
 
 def encode_pack_ef(g_plane: jax.Array, e_plane: jax.Array):
     """EF inject + sign pack in one step: (words, g_eff plane).
@@ -234,7 +221,7 @@ def vote_pipeline_dense(stack: jax.Array, num_workers: int,
 
     The whole local (no-collective) vote datapath in one step:
     encode -> popcount -> majority -> decode, never leaving registers in
-    the fused kernel.  Reference composition of the staged kernels.
+    the fused kernel.  Composition of the per-stage references.
     """
     packed = jnp.stack([sign_pack(stack[w]) for w in range(stack.shape[0])])
     sw, mw = vote_combine(packed, num_workers, gate_words)
@@ -245,7 +232,7 @@ def ef_residual(plane: jax.Array, beta) -> jax.Array:
     """EF residual update on a value plane: x - beta * sgn(x).
 
     Reference for the fused residual kernel; elementwise-identical to
-    the unfused ``g_eff - beta * jnp.sign(g_eff)`` on the leaf shape.
+    ``g_eff - beta * jnp.sign(g_eff)`` on the leaf shape.
     """
     b = jnp.asarray(beta, plane.dtype)
     return plane - b * jnp.sign(plane)

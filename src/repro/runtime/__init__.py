@@ -1,14 +1,13 @@
 """Training/serving runtime: step builders, Trainer, fault tolerance."""
 from .fault import (FailureInjector, SimulatedFailure, StragglerWatchdog,
                     StepTimer)
-from .train import (Trainer, TrainerConfig, TrainState, build_train_step,
-                    dp_num_workers)
+from .train import Trainer, TrainerConfig, TrainState, dp_num_workers
 from .serve import (build_cached_prefill, build_prefill, build_serve_step,
                     serve_shardings)
 
 __all__ = [
     "FailureInjector", "SimulatedFailure", "StragglerWatchdog", "StepTimer",
-    "Trainer", "TrainerConfig", "TrainState", "build_train_step",
-    "dp_num_workers", "build_cached_prefill", "build_prefill",
-    "build_serve_step", "serve_shardings",
+    "Trainer", "TrainerConfig", "TrainState", "dp_num_workers",
+    "build_cached_prefill", "build_prefill", "build_serve_step",
+    "serve_shardings",
 ]

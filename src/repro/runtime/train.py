@@ -47,33 +47,7 @@ log = logging.getLogger("repro.train")
 #: a host span in the profiler's trace; costs next to nothing untraced
 _span = jax.profiler.TraceAnnotation
 
-__all__ = ["TrainState", "Trainer", "TrainerConfig", "build_train_step",
-           "dp_num_workers"]
-
-
-# ---------------------------------------------------------------------------
-# step builder (legacy shim)
-# ---------------------------------------------------------------------------
-
-def build_train_step(cfg: ModelConfig, mesh, optimizer: Optimizer,
-                     plan: AdmissionPlan, params_like: Any, *,
-                     dp_axes=("data",), rules: GroupRules | None = None,
-                     with_diagnostics: bool = False,
-                     loss: Callable | None = None,
-                     zero1: bool = True,
-                     grad_accum: int = 1,
-                     donate: bool = True) -> CompiledStep:
-    """Deprecated free-function shim — use ``Fabric(...).build_step``.
-
-    Constructs a throwaway session and compiles one step; returns the
-    legacy 4-tuple-compatible :class:`CompiledStep`
-    ``(jitted, state_shardings, batch_sharding, aux)``.
-    """
-    fabric = Fabric(mesh, dp_axes, rules=rules)
-    return fabric.build_step(cfg, optimizer, plan, params_like,
-                             with_diagnostics=with_diagnostics, loss=loss,
-                             zero1=zero1, grad_accum=grad_accum,
-                             donate=donate)
+__all__ = ["TrainState", "Trainer", "TrainerConfig", "dp_num_workers"]
 
 
 # ---------------------------------------------------------------------------

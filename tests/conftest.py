@@ -14,3 +14,20 @@ import pytest
 def rng():
     return np.random.RandomState(0)
 
+
+
+@pytest.fixture
+def aggregate():
+    """``aggregate(fabric, grads, plan, ef=None, fused=True)``: the
+    session's bucketed ``Fabric.aggregate`` or, with ``fused=False``, the
+    per-leaf reference ``aggregate_tree`` under the same policies."""
+    from repro.core import AdmissionPlan
+    from repro.fabric import aggregate_tree
+
+    def run(fabric, grads, plan, ef=None, *, fused=True):
+        if fused:
+            return fabric.aggregate(grads, plan, ef=ef)
+        policies = (fabric.resolve(grads, plan)
+                    if isinstance(plan, AdmissionPlan) else plan)
+        return aggregate_tree(fabric.context, grads, policies, ef_states=ef)
+    return run

@@ -188,7 +188,8 @@ def test_wire_schedule_mean_codec_never_on_vote_transport():
 
 @pytest.mark.parametrize("schedule", [None, Schedule.VOTE_PSUM])
 @pytest.mark.parametrize("fused", [False, True])
-def test_builtin_codecs_bit_identical_string_vs_enum(rng, schedule, fused):
+def test_builtin_codecs_bit_identical_string_vs_enum(rng, aggregate, schedule,
+                                                    fused):
     """Naming built-in codecs by string is bit-for-bit the enum path —
     per-leaf and fused (packed_a2a needs real/virtual workers and is
     covered by test_string_named_packed_a2a_virtual_workers)."""
@@ -202,19 +203,20 @@ def test_builtin_codecs_bit_identical_string_vs_enum(rng, schedule, fused):
              "embed": GroupPolicy(embed, schedule)},
             default=GroupPolicy(AggregationMode.FP32))
 
-    want, _ = fabric.aggregate(
-        grads, plan((AggregationMode.G_BINARY, AggregationMode.G_TERNARY)),
+    want, _ = aggregate(
+        fabric, grads,
+        plan((AggregationMode.G_BINARY, AggregationMode.G_TERNARY)),
         fused=fused)
-    got, _ = fabric.aggregate(grads, plan(("gbinary", "gternary")),
-                              fused=fused)
+    got, _ = aggregate(fabric, grads, plan(("gbinary", "gternary")),
+                       fused=fused)
     assert _tree_equal(want, got)
 
 
-def test_fp32_codec_is_exact_pmean(rng):
+def test_fp32_codec_is_exact_pmean(rng, aggregate):
     grads = _grads(rng)
     for fused in (False, True):
-        agg, _ = Fabric().aggregate(grads, AdmissionPlan.fp32_all(),
-                                    fused=fused)
+        agg, _ = aggregate(Fabric(), grads, AdmissionPlan.fp32_all(),
+                           fused=fused)
         ref = jax.tree.map(lambda g: fp32_allreduce(g, ()), grads)
         assert _tree_equal(agg, ref)
 
@@ -240,7 +242,7 @@ def test_vote_codecs_match_dense_oracle_multiworker(rng):
         np.testing.assert_array_equal(np.asarray(got[0]), want)
 
 
-def test_string_named_packed_a2a_virtual_workers(rng):
+def test_string_named_packed_a2a_virtual_workers(rng, aggregate):
     """String-named codecs on the packed controller schedule (W=4 vmap)
     are bit-identical to the enum-named path, fused and per-leaf."""
     w = 4
@@ -258,7 +260,7 @@ def test_string_named_packed_a2a_virtual_workers(rng):
     for fused in (False, True):
         def run(p, fused=fused):
             def one(g):
-                agg, _ = fabric.aggregate(g, p, fused=fused)
+                agg, _ = aggregate(fabric, g, p, fused=fused)
                 return agg
             return jax.vmap(one, axis_name="w")(gs)
 
@@ -278,7 +280,7 @@ def test_codec_threads_ef_flag_gates_fused_ef(rng):
     fabric = Fabric()
     policies = fabric.resolve(grads, plan)
     ef = init_ef_states(grads, policies)
-    _, new_ef = fabric.aggregate(grads, plan, ef=ef, fused=True)
+    _, new_ef = fabric.aggregate(grads, plan, ef=ef)
     assert _tree_equal(ef, new_ef)       # int4 declares threads_ef=False
 
 
@@ -431,7 +433,7 @@ def test_custom_codec_registered_in_test_runs_everywhere(rng):
         unregister_codec("halfmean")
 
 
-def test_custom_vote_codec_without_ef_consistent_across_paths(rng):
+def test_custom_vote_codec_without_ef_consistent_across_paths(rng, aggregate):
     """A vote codec with threads_ef=False: the per-leaf path must apply
     the same EF gate as the fused path — no injection, residuals
     untouched, aggregates identical on both routes."""
@@ -448,8 +450,8 @@ def test_custom_vote_codec_without_ef_consistent_across_paths(rng):
         plan = AdmissionPlan.lowbit_all("vote_noef", error_feedback=True)
         fabric = Fabric()
         ef = {"a": jnp.asarray(rng.randn(1, 33, 5), jnp.float32)}
-        a1, e1 = fabric.aggregate(grads, plan, ef=ef, fused=True)
-        a2, e2 = fabric.aggregate(grads, plan, ef=ef, fused=False)
+        a1, e1 = aggregate(fabric, grads, plan, ef=ef)
+        a2, e2 = aggregate(fabric, grads, plan, ef=ef, fused=False)
         assert _tree_equal(a1, a2)
         # the residual is neither injected (W=1 vote == sign(g), not
         # sign(g + e)) nor updated, on either path
@@ -460,7 +462,7 @@ def test_custom_vote_codec_without_ef_consistent_across_paths(rng):
         unregister_codec("vote_noef")
 
 
-def test_custom_leaf_gate_mask_same_zeros_on_both_vote_transports(rng):
+def test_custom_leaf_gate_mask_same_zeros_on_both_vote_transports(rng, aggregate):
     """A gated codec with a custom keep pattern zeroes the same elements
     on vote_psum and packed_a2a, per-leaf and fused (W=4 vmap)."""
     def even_mask(n):
@@ -492,7 +494,7 @@ def test_custom_leaf_gate_mask_same_zeros_on_both_vote_transports(rng):
             plan = AdmissionPlan.lowbit_all("even_keep", schedule=schedule)
             for fused in (False, True):
                 def one(g, plan=plan, fused=fused):
-                    agg, _ = fabric.aggregate(g, plan, fused=fused)
+                    agg, _ = aggregate(fabric, g, plan, fused=fused)
                     return agg
                 got = jax.vmap(one, axis_name="w")(gs)
                 np.testing.assert_array_equal(
@@ -550,7 +552,7 @@ def test_parameterized_codec_carries_registration_name():
         unregister_codec("top25pct")
 
 
-def test_ungated_codec_with_leaf_gate_mask_raises(rng):
+def test_ungated_codec_with_leaf_gate_mask_raises(rng, aggregate):
     """A codec supplying a keep mask while declaring gated=False is a
     contract violation — it must fail loudly on both paths, never
     silently drop the gate."""
@@ -570,7 +572,7 @@ def test_ungated_codec_with_leaf_gate_mask_raises(rng):
         plan = AdmissionPlan.lowbit_all("bad_gate")
         for fused in (True, False):
             with pytest.raises(ValueError, match="gated=False"):
-                Fabric().aggregate(grads, plan, fused=fused)
+                aggregate(Fabric(), grads, plan, fused=fused)
     finally:
         unregister_codec("bad_gate")
 

@@ -195,7 +195,7 @@ def test_grad_accumulation_equivalence():
     from repro.optim import AdamW
     from repro.core import AdmissionPlan
     from repro.runtime import Trainer, TrainerConfig
-    from repro.runtime.train import build_train_step
+    from repro.fabric import Fabric
     from repro.data import SyntheticLMStream
 
     mesh = jax.make_mesh((4, 2), ("data", "model"),
@@ -211,9 +211,8 @@ def test_grad_accumulation_equivalence():
         tr = Trainer(cfg, mesh, opt, data, plan=plan,
                      tcfg=TrainerConfig(dp_axes=("data",), log_interval=1000))
         tr.init_state()
-        jitted, _, b_sh, _ = build_train_step(
-            cfg, mesh, opt, plan, tr.state.params, dp_axes=("data",),
-            grad_accum=ga, donate=False)
+        jitted, _, b_sh, _ = Fabric(mesh, ("data",)).build_step(
+            cfg, opt, plan, tr.state.params, grad_accum=ga, donate=False)
         st = tr.state
         for step in range(6):
             b = jax.tree.map(lambda a: jax.device_put(jnp.asarray(a), b_sh),

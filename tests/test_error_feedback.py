@@ -10,8 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.lowbit import _ef_inject, _ef_update, lowbit_vote_psum
-from repro.core import aggregate_gradients, init_ef_states, resolve_policies
+from repro.core import init_ef_states, resolve_policies
 from repro.core import AdmissionPlan, AggregationMode, GroupPolicy
+from repro.fabric import Fabric
 
 
 def test_residual_update_formula(rng):
@@ -42,7 +43,7 @@ def test_ef_accumulates_what_compression_discards(rng):
 
 
 def test_ef_states_threaded_through_aggregation(rng):
-    """aggregate_gradients round-trips EF sentinels and residuals."""
+    """Fabric.aggregate round-trips EF sentinels and residuals."""
     params = {"backbone": {"w": jnp.zeros((64, 64))},
               "head": {"w": jnp.zeros((64, 8))}}
     plan = AdmissionPlan.from_dict(
@@ -56,7 +57,7 @@ def test_ef_states_threaded_through_aggregation(rng):
 
     grads = jax.tree.map(lambda p: jnp.asarray(
         rng.randn(*p.shape), jnp.float32), params)
-    agg, new_ef = aggregate_gradients(grads, policies, (), 1, ef_states=ef)
+    agg, new_ef = Fabric().aggregate(grads, policies, ef=ef)
     # W=1: aggregate is sign(g); residual is g - mean|g|*sign(g)
     np.testing.assert_array_equal(np.asarray(agg["backbone"]["w"]),
                                   np.sign(np.asarray(grads["backbone"]["w"])))

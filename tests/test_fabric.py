@@ -2,7 +2,7 @@
 
 Covers the registry contract (round-trip, unknown-name error, duplicate
 protection), bit-for-bit equivalence of ``Fabric.aggregate`` with the
-legacy ``aggregate_gradients`` free function on a mixed plan, EF spec
+per-leaf reference ``aggregate_tree`` on a mixed plan, EF spec
 construction, wire-byte accounting through backends, and — the extension
 seam the registry exists for — training with a custom schedule that was
 registered without modifying any core file.
@@ -18,10 +18,10 @@ import numpy as np
 import pytest
 
 from repro.core import (AdmissionPlan, AggregationMode, GroupPolicy,
-                        Schedule, aggregate_gradients, init_ef_states,
-                        resolve_policies, wire_bytes_per_device)
+                        Schedule, init_ef_states, resolve_policies,
+                        wire_bytes_per_device)
 from repro.fabric import (AggregationContext, Fabric, ScheduleBackend,
-                          available_schedules, get_schedule,
+                          aggregate_tree, available_schedules, get_schedule,
                           register_schedule, unregister_schedule)
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -117,7 +117,7 @@ def test_fabric_aggregate_matches_legacy_bitwise(rng):
     plan = _mixed_plan()
     policies = resolve_policies(grads, plan)
 
-    want, want_ef = aggregate_gradients(grads, policies, (), 1)
+    want, want_ef = aggregate_tree(AggregationContext(), grads, policies)
     got, got_ef = Fabric().aggregate(grads, plan)
 
     for path in (("backbone", "w1"), ("backbone", "w2"), ("embed", "table"),
@@ -138,7 +138,8 @@ def test_fabric_aggregate_matches_legacy_with_error_feedback(rng):
     policies = resolve_policies(grads, plan)
     ef = init_ef_states(grads, policies)
 
-    want, want_ef = aggregate_gradients(grads, policies, (), 1, ef_states=ef)
+    want, want_ef = aggregate_tree(AggregationContext(), grads, policies,
+                                   ef_states=ef)
     got, got_ef = Fabric().aggregate(grads, plan, ef=ef)
 
     np.testing.assert_array_equal(np.asarray(want["backbone"]["w1"]),

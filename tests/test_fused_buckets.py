@@ -1,8 +1,8 @@
 """Bucketed (fused) aggregation: layout planner + bit-for-bit equivalence.
 
-The fusion contract: `aggregate_tree_bucketed` / `Fabric.aggregate(fused=
-True)` must be *bit-identical* — aggregates and EF states — to the
-per-leaf path for every built-in schedule, in every mode, with and
+The fusion contract: `aggregate_tree_bucketed` / `Fabric.aggregate` must
+be *bit-identical* — aggregates and EF states — to the per-leaf reference
+`aggregate_tree` for every built-in schedule, in every mode, with and
 without error feedback, for any gate phase.  Multi-worker semantics are
 exercised with virtual workers via ``jax.vmap(..., axis_name='w')``
 (psum/all_to_all/all_gather resolve against the vmapped axis exactly as
@@ -158,14 +158,15 @@ def test_gate_phase_normalized_for_non_ternary_modes(rng):
 
 @pytest.mark.parametrize("schedule", [None, Schedule.VOTE_PSUM])
 @pytest.mark.parametrize("error_feedback", [False, True])
-def test_fused_matches_per_leaf_host_local(rng, schedule, error_feedback):
+def test_fused_matches_per_leaf_host_local(rng, aggregate, schedule,
+                                           error_feedback):
     grads = _grads(rng)
     plan = _plan(schedule=schedule, error_feedback=error_feedback)
     fabric = Fabric()
     policies = fabric.resolve(grads, plan)
     ef = init_ef_states(grads, policies) if error_feedback else None
-    want, want_ef = fabric.aggregate(grads, plan, ef=ef, fused=False)
-    got, got_ef = fabric.aggregate(grads, plan, ef=ef, fused=True)
+    want, want_ef = aggregate(fabric, grads, plan, ef=ef, fused=False)
+    got, got_ef = aggregate(fabric, grads, plan, ef=ef)
     assert _tree_equal(want, got)
     if error_feedback:
         assert _tree_equal(want_ef, got_ef)
@@ -192,7 +193,7 @@ def test_fused_matches_per_leaf_all_gate_phases(rng, mode, gate_phase):
 @pytest.mark.parametrize("schedule", [Schedule.VOTE_PSUM,
                                       Schedule.PACKED_A2A])
 @pytest.mark.parametrize("error_feedback", [False, True])
-def test_fused_matches_per_leaf_virtual_workers(rng, schedule,
+def test_fused_matches_per_leaf_virtual_workers(rng, aggregate, schedule,
                                                 error_feedback):
     """W=4 virtual workers via vmap: binary + ternary + FP32 mixed plan.
 
@@ -217,8 +218,8 @@ def test_fused_matches_per_leaf_virtual_workers(rng, schedule,
 
     def run(fused):
         def one(g, e):
-            return fabric.aggregate(
-                g, plan, ef=(e if error_feedback else None), fused=fused)
+            return aggregate(fabric, g, plan,
+                             ef=(e if error_feedback else None), fused=fused)
         return jax.vmap(one, axis_name="w")(gs, efs)
 
     want, want_ef = run(False)
@@ -235,12 +236,11 @@ def test_fused_matches_per_leaf_virtual_workers(rng, schedule,
                                   want_ter)
 
 
-def test_fused_is_the_default_aggregate_path(rng):
+def test_fused_is_the_default_aggregate_path(rng, aggregate):
     grads = _grads(rng)
     fabric = Fabric()
-    assert fabric.fused
-    got, _ = fabric.aggregate(grads, _plan())           # default route
-    want, _ = fabric.aggregate(grads, _plan(), fused=False)
+    got, _ = fabric.aggregate(grads, _plan())           # the only route
+    want, _ = aggregate(fabric, grads, _plan(), fused=False)
     assert _tree_equal(want, got)
     # the layout is planned once and cached per (tree, policies) signature
     lay = fabric.layout_for(grads, _plan())
@@ -314,15 +314,15 @@ def test_layout_cache_invalidated_when_backend_swapped(rng):
         unregister_schedule("toy_swappable")
 
 
-def test_mixed_dtypes_never_share_a_bucket(rng):
+def test_mixed_dtypes_never_share_a_bucket(rng, aggregate):
     grads = {"a": jnp.asarray(rng.randn(8), jnp.float32),
              "b": jnp.asarray(rng.randn(8), jnp.bfloat16)}
     plan = AdmissionPlan.lowbit_all(AggregationMode.G_BINARY)
     fabric = Fabric()
     layout = fabric.layout_for(grads, plan)
     assert len(layout.buckets) == 2
-    want, _ = fabric.aggregate(grads, plan, fused=False)
-    got, _ = fabric.aggregate(grads, plan, fused=True)
+    want, _ = aggregate(fabric, grads, plan, fused=False)
+    got, _ = aggregate(fabric, grads, plan)
     assert _tree_equal(want, got)
     assert got["b"].dtype == jnp.bfloat16
 

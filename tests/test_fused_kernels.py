@@ -1,14 +1,16 @@
 """Codec-owned fused Pallas kernels (repro.kernels.fused).
 
-The contract under test: every fused kernel is **bit-identical** to the
-staged reference composition wherever the staged path runs — per stage
-(hypothesis round trips on ragged sizes, W in {3, 31, 128, 256}), and
-end-to-end through the Fabric session (``fused_kernels`` True vs False,
-EF on/off, fused buckets and per-leaf, flat and hierarchical routes).
-Comparisons against the jnp reference jit the reference side: XLA CPU
-rounds an eager scalar division differently from the jitted program the
-kernels (and every production step) run in, and bit-identity is a claim
-about compiled programs (DESIGN.md section 12).
+The contract under test: every fused kernel is **bit-identical** to its
+pure-jnp reference composition in :mod:`repro.kernels.ref` — per stage
+(ragged sizes, W in {2, 3, 8, 16, 32} and {3, 31, 128, 256}), for the
+distributed packed vote under vmapped virtual workers, and end-to-end
+through the Fabric session against the dense Section-2 oracle (EF on/off,
+one bucket per leaf or the default budget, flat and hierarchical routes)
+or, for the mean codecs, the codec's own jnp encode/decode.  Comparisons
+against the jnp reference jit the reference side: XLA CPU rounds an eager
+scalar division differently from the jitted program the kernels (and
+every production step) run in, and bit-identity is a claim about
+compiled programs (DESIGN.md section 12).
 
 Also covered: the KernelSet launch/HBM accounting invariants the
 nightly benchmark gate relies on, the ``layout_kernel_stats`` roll-up,
@@ -24,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import AdmissionPlan, init_ef_states, resolve_policies
+from repro.core import (DEFAULT_BUCKET_BYTES, AdmissionPlan, init_ef_states,
+                        resolve_policies)
 from repro.fabric import (Fabric, HopPlan, HopSpec, get_codec,
                           layout_kernel_stats, register_hop_plan,
                           unregister_hop_plan)
@@ -55,6 +58,26 @@ def _stack_planes(rng, w, n):
     return jnp.stack([ref.to_plane(vals[i]) for i in range(w)])
 
 
+def _dense_vote(x, ternary, phase=0):
+    """(W, ...) worker values -> the Section-2 aggregate of their signs."""
+    w = x.shape[0]
+    flat = jnp.asarray(x, jnp.float32).reshape(w, -1)
+    if ternary:
+        u = jax.jit(ref.gternary_aggregate_dense,
+                    static_argnums=1)(flat, phase)
+    else:
+        u = jax.jit(ref.gbinary_aggregate_dense)(flat)
+    return np.asarray(u).reshape(x.shape[1:])
+
+
+def _ef_signsgd_residual(x):
+    """EF-signSGD residual x - mean|x| * sgn(x), per worker, in numpy."""
+    x = np.asarray(x, np.float32)
+    axes = tuple(range(1, x.ndim))
+    beta = np.mean(np.abs(x), axis=axes, keepdims=True, dtype=np.float64)
+    return x - beta.astype(np.float32) * np.sign(x)
+
+
 # ---------------------------------------------------------------------------
 # per-stage bit-identity: fused kernel vs jitted reference
 # ---------------------------------------------------------------------------
@@ -70,6 +93,24 @@ def test_vote_pipeline_matches_ref_w_sweep(rng, w, ternary):
         stack, w, gate).astype(jnp.float32)
     got = fused.vote_pipeline(stack, gate, num_workers=w, interpret=True)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("rows", [32, 64, 256, 1024])
+@pytest.mark.parametrize("w", [2, 3, 8, 16, 32])
+@pytest.mark.parametrize("gated", [False, True])
+def test_vote_combine_matches_ref(rng, rows, w, gated):
+    """PopCount + majority in one kernel, over (rows, LANE) value planes
+    of W workers, with and without the 2-of-3 gate."""
+    planes = jnp.asarray(rng.randn(w, rows, ref.LANE), jnp.float32)
+    routed = jnp.stack([ref.sign_pack(planes[i]) for i in range(w)])
+    gate = (ref.ternary_gate_words(rows) if gated else
+            fused.local_gate_words(rows // ref.PACK, ternary=False))
+    want_s, want_m = jax.jit(ref.vote_combine, static_argnums=1)(
+        routed, w, gate)
+    got_s, got_m = fused.vote_combine(routed, gate, num_workers=w,
+                                      interpret=True)
+    np.testing.assert_array_equal(np.asarray(want_s), np.asarray(got_s))
+    np.testing.assert_array_equal(np.asarray(want_m), np.asarray(got_m))
 
 
 @pytest.mark.parametrize("w", W_SWEEP)
@@ -120,90 +161,169 @@ def test_threshold_mask_matches_ref(rng):
 
 
 # ---------------------------------------------------------------------------
-# Fabric end-to-end: fused_kernels True vs False, bit-identical
+# the distributed packed vote under vmapped virtual workers
 # ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("w", [2, 3, 4, 8])
+@pytest.mark.parametrize("ternary", [False, True])
+@pytest.mark.parametrize("ef", [False, True])
+@pytest.mark.parametrize("n", [5000, 3 * 4096])
+def test_fused_packed_vote_distributed_matches_dense_oracle(rng, w, ternary,
+                                                            ef, n):
+    """Encode, all_to_all of the packed words, vote_combine, all_gather,
+    decode over W virtual workers == the dense vote of g + e; the
+    residual is EF-signSGD's."""
+    g = jnp.asarray(rng.randn(w, n), jnp.float32)
+    e = jnp.asarray(rng.randn(w, n), jnp.float32) if ef else None
+
+    def one(g, e):
+        return fused.fused_packed_vote(g, ("w",), w, ternary=ternary, ef=e,
+                                       interpret=True)
+
+    if ef:
+        u, new_e = jax.vmap(one, axis_name="w")(g, e)
+    else:
+        u, new_e = jax.vmap(lambda x: one(x, None), axis_name="w")(g)
+    x = np.asarray(g) + (np.asarray(e) if ef else 0.0)
+    want = _dense_vote(x, ternary)
+    for k in range(w):
+        np.testing.assert_array_equal(np.asarray(u[k]), want)
+    if ef:
+        np.testing.assert_allclose(np.asarray(new_e), _ef_signsgd_residual(x),
+                                   rtol=1e-6, atol=1e-6)
+    else:
+        assert new_e is None
+
+
+# ---------------------------------------------------------------------------
+# Fabric end-to-end against the dense oracle
+# ---------------------------------------------------------------------------
+
+def _check_against_oracle(policies, gs, efs, got, got_ef):
+    """Each worker's aggregate of a W-stacked tree == the dense vote of
+    g + e on voted leaves and the float32 mean on FP32 leaves; EF
+    residuals are EF-signSGD's."""
+    flat = lambda t: jax.tree_util.tree_flatten(
+        t, is_leaf=lambda x: hasattr(x, "mode"))[0]
+    pols, g_l, u_l = flat(policies), flat(gs), flat(got)
+    e_l = flat(efs) if efs is not None else [None] * len(g_l)
+    ne_l = flat(got_ef) if got_ef is not None else [None] * len(g_l)
+    for pol, g, u, e, ne in zip(pols, g_l, u_l, e_l, ne_l):
+        codec = get_codec(pol.mode)
+        if codec.reduction != "vote":
+            want = np.asarray(jnp.mean(g, axis=0))
+            for k in range(g.shape[0]):
+                np.testing.assert_array_equal(np.asarray(u[k]), want)
+            continue
+        use_ef = pol.error_feedback and e is not None and e.ndim > 1
+        x = np.asarray(g) + (np.asarray(e)[:, 0] if use_ef else 0.0)
+        want = _dense_vote(x, codec.gated, pol.gate_phase)
+        for k in range(g.shape[0]):
+            np.testing.assert_array_equal(np.asarray(u[k]), want)
+        if use_ef:
+            np.testing.assert_allclose(np.asarray(ne)[:, 0],
+                                       _ef_signsgd_residual(x),
+                                       rtol=1e-6, atol=1e-6)
+
 
 @pytest.mark.parametrize("interpret", [None, True])
 @pytest.mark.parametrize("mode", ["gbinary", "gternary"])
 @pytest.mark.parametrize("error_feedback", [False, True])
-@pytest.mark.parametrize("fused_buckets", [True, False])
+@pytest.mark.parametrize("bucket_bytes", [DEFAULT_BUCKET_BYTES, 4],
+                         ids=["default", "leaf_per_bucket"])
 def test_fabric_fused_kernels_bit_identical_packed(rng, interpret, mode,
                                                    error_feedback,
-                                                   fused_buckets):
+                                                   bucket_bytes):
+    """W=4 virtual workers on packed_a2a: the default bucket budget, and
+    one below the smallest leaf so every leaf gets its own bucket."""
     w = 4
     gs = _grads(rng, w=w)
     plan = AdmissionPlan.lowbit_backbone(mode, schedule="packed_a2a",
                                          error_feedback=error_feedback)
-    f_on = Fabric(dp_axes=("w",), num_workers=w, interpret=interpret,
-                  fused_kernels=True)
-    f_off = Fabric(dp_axes=("w",), num_workers=w, interpret=interpret,
-                   fused_kernels=False)
+    f = Fabric(dp_axes=("w",), num_workers=w, interpret=interpret,
+               bucket_bytes=bucket_bytes)
     g0 = jax.tree.map(lambda x: x[0], gs)
+    policies = f.resolve(g0, plan)
+    layout = f.layout_for(g0, policies)
+    if bucket_bytes == 4:
+        assert layout.num_launches == layout.num_leaves
+    else:
+        assert layout.num_launches < layout.num_leaves
     if error_feedback:
-        ef0 = init_ef_states(g0, f_on.resolve(g0, plan))
+        ef0 = init_ef_states(g0, policies)
         efs = jax.tree.map(
             lambda e: (jnp.asarray(rng.randn(w, *e.shape), jnp.float32)
                        if e.ndim > 0 else jnp.zeros((w,) + e.shape)), ef0)
+        got, got_ef = jax.vmap(lambda g, e: f.aggregate(g, plan, ef=e),
+                               axis_name="w")(gs, efs)
     else:
-        efs = None
-
-    def run(f):
-        def one(g, e):
-            return f.aggregate(g, plan, ef=e, fused=fused_buckets)
-        if efs is None:
-            return jax.vmap(lambda g: one(g, None), axis_name="w")(gs)
-        return jax.vmap(one, axis_name="w")(gs, efs)
-
-    want, want_ef = run(f_off)
-    got, got_ef = run(f_on)
-    assert _tree_equal(want, got)
-    if error_feedback:
-        assert _tree_equal(want_ef, got_ef)
+        efs = got_ef = None
+        got, _ = jax.vmap(lambda g: f.aggregate(g, plan),
+                          axis_name="w")(gs)
+    _check_against_oracle(policies, gs, efs, got, got_ef)
 
 
 @pytest.mark.parametrize("mode", ["int4", "topk"])
 def test_fabric_fused_kernels_bit_identical_means(rng, mode):
-    """Mean codecs: kernel encode == jnp encode inside one jit program."""
+    """Mean codecs: the kernel encode == the codec's own jnp
+    encode/decode over each bucket's flat payload, in one jit program."""
     gs = _grads(rng)
     plan = AdmissionPlan.lowbit_backbone(mode)
-    f_on = Fabric(interpret=True, fused_kernels=True)
-    f_off = Fabric(interpret=True, fused_kernels=False)
-    pol = f_on.resolve(gs, plan)
-    a_on = jax.jit(lambda g: f_on.aggregate(g, pol)[0])(gs)
-    a_off = jax.jit(lambda g: f_off.aggregate(g, pol)[0])(gs)
-    assert _tree_equal(a_on, a_off)
+    f = Fabric(interpret=True)
+    pol = f.resolve(gs, plan)
+    layout = f.layout_for(gs, pol)
+    assert any(get_codec(b.key.mode).pallas_kernels() is not None
+               for b in layout.buckets)
+
+    def oracle(tree):
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        out = [None] * len(leaves)
+        for b in layout.buckets:
+            codec = get_codec(b.key.mode)
+            flat = jnp.concatenate([leaves[s.leaf].reshape(-1)
+                                    for s in b.slots])
+            u = codec.decode(f.context, codec.encode(f.context, flat))
+            for s in b.slots:
+                out[s.leaf] = u[s.offset:s.offset + s.size].reshape(s.shape)
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    want = jax.jit(oracle)(gs)
+    got = jax.jit(lambda g: f.aggregate(g, pol)[0])(gs)
+    assert _tree_equal(want, got)
+
+
+def _pallas_names(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _pallas_names(sub)
+    return out
 
 
 @pytest.mark.parametrize("mode", ["gbinary", "gternary"])
 def test_fabric_host_local_single_launch_pipeline(rng, mode):
-    """Host-local packed vote: the fused path is ONE vote_pipeline kernel;
-    still bit-identical to the staged local fallback."""
+    """Host-local packed vote: the whole chain is ONE vote_pipeline
+    kernel per bucket, equal to the one-worker dense oracle."""
     gs = _grads(rng)
     plan = AdmissionPlan.lowbit_backbone(mode, schedule="packed_a2a")
-    f_on = Fabric(interpret=True, fused_kernels=True)
-    f_off = Fabric(interpret=True, fused_kernels=False)
-    pol = f_on.resolve(gs, plan)
-    # jit: the staged path's empty-axes all_to_all only lowers inside a
-    # compiled program (and production steps are always jitted)
-    a_on = jax.jit(lambda g: f_on.aggregate(g, pol)[0])(gs)
-    a_off = jax.jit(lambda g: f_off.aggregate(g, pol)[0])(gs)
-    assert _tree_equal(a_on, a_off)
-
-
-def test_fabric_vote_psum_ignores_kernel_sets(rng):
-    """Dense vote_psum has no packed stages to fuse: fused_kernels is a
-    no-op there by design (documented in backends.py)."""
-    gs = _grads(rng)
-    plan = AdmissionPlan.lowbit_backbone("gbinary")      # default vote_psum
-    a_on, _ = Fabric(fused_kernels=True).aggregate(gs, plan)
-    a_off, _ = Fabric(fused_kernels=False).aggregate(gs, plan)
-    assert _tree_equal(a_on, a_off)
+    f = Fabric(interpret=True)
+    pol = f.resolve(gs, plan)
+    run = jax.jit(lambda g: f.aggregate(g, pol)[0])
+    names = _pallas_names(jax.make_jaxpr(run)(gs).jaxpr)
+    votes = [b for b in f.layout_for(gs, pol).buckets
+             if get_codec(b.key.mode).reduction == "vote"]
+    assert names == ["fabric_vote_pipeline"] * len(votes)
+    got = jax.tree.map(lambda x: x[None], run(gs))
+    _check_against_oracle(pol, jax.tree.map(lambda x: x[None], gs), None,
+                          got, None)
 
 
 @pytest.mark.parametrize("error_feedback", [False, True])
 def test_fabric_hierarchical_hop_kernels_bit_identical(rng, error_feedback):
     """Per-hop kernel resolution: a 2-hop fp32 -> gbinary/packed_a2a route
-    aggregates bit-identically with kernels on and off."""
+    == the vote over the outer axis of the inner axis' mean of g + e."""
     outer, inner = 2, 2
     w = outer * inner
     gs = jax.tree.map(
@@ -221,22 +341,27 @@ def test_fabric_hierarchical_hop_kernels_bit_identical(rng, error_feedback):
             lambda e: (jnp.asarray(rng.randn(outer, inner, *e.shape),
                                    e.dtype) if e.ndim > 0
                        else jnp.zeros((outer, inner) + e.shape)), ef0)
+        f = Fabric(dp_axes=("outer", "inner"), num_workers=w)
 
-        def run(fused_kernels):
-            f = Fabric(dp_axes=("outer", "inner"), num_workers=w,
-                       fused_kernels=fused_kernels)
-
-            def one(g, e):
-                return f.aggregate(
-                    g, plan, ef=(e if error_feedback else None))
-            return jax.vmap(jax.vmap(one, axis_name="inner"),
-                            axis_name="outer")(gs, efs)
-
-        want, want_ef = run(False)
-        got, got_ef = run(True)
-        assert _tree_equal(want, got)
-        if error_feedback:
-            assert _tree_equal(want_ef, got_ef)
+        def one(g, e):
+            return f.aggregate(g, plan, ef=(e if error_feedback else None))
+        got, got_ef = jax.vmap(jax.vmap(one, axis_name="inner"),
+                               axis_name="outer")(gs, efs)
+        for path in (("backbone", "w1"), ("backbone", "w2"),
+                     ("head", "w"), ("norms", "scale")):
+            g, e = gs[path[0]][path[1]], efs[path[0]][path[1]]
+            x = np.asarray(g) + (np.asarray(e)[:, :, 0] if error_feedback
+                                 else 0.0)
+            want = _dense_vote(jnp.mean(jnp.asarray(x), axis=1), False)
+            u = np.asarray(got[path[0]][path[1]])
+            for i in range(outer):
+                for j in range(inner):
+                    np.testing.assert_array_equal(u[i, j], want)
+            if error_feedback:
+                ne = np.asarray(got_ef[path[0]][path[1]])[:, :, 0]
+                resid = _ef_signsgd_residual(x.reshape((w,) + x.shape[2:]))
+                np.testing.assert_allclose(ne.reshape(resid.shape), resid,
+                                           rtol=1e-6, atol=1e-6)
     finally:
         unregister_hop_plan("fk_hier")
 
@@ -301,13 +426,8 @@ def test_layout_kernel_stats_rollup(rng):
 
 
 # ---------------------------------------------------------------------------
-# session integration: context flag + cache keys + signatures
+# session integration: cache keys + signatures
 # ---------------------------------------------------------------------------
-
-def test_context_carries_fused_kernels_flag():
-    assert Fabric().context.fused_kernels is True
-    assert Fabric(fused_kernels=False).context.fused_kernels is False
-
 
 def test_kernel_signatures():
     assert get_codec("gbinary").kernel_signature() == "vote:v1"

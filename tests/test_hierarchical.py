@@ -108,7 +108,7 @@ def test_hier_codec_contract_delegates_to_hops():
 
 @pytest.mark.parametrize("mode", FLAT_CODECS)
 @pytest.mark.parametrize("fused", [False, True])
-def test_one_hop_plan_matches_flat_backend(rng, mode, fused):
+def test_one_hop_plan_matches_flat_backend(rng, aggregate, mode, fused):
     w, name = 4, f"hier1_{mode}"
     gs = _grads(rng, w=w)
     register_hop_plan(HopPlan(name, (HopSpec(mode),)))
@@ -117,7 +117,7 @@ def test_one_hop_plan_matches_flat_backend(rng, mode, fused):
 
         def run(plan):
             def one(g):
-                return fabric.aggregate(g, plan, fused=fused)[0]
+                return aggregate(fabric, g, plan, fused=fused)[0]
             return jax.vmap(one, axis_name="w")(gs)
 
         flat = run(AdmissionPlan.lowbit_all(mode))
@@ -129,7 +129,8 @@ def test_one_hop_plan_matches_flat_backend(rng, mode, fused):
 
 @pytest.mark.parametrize("mode", ["gbinary", "gternary"])
 @pytest.mark.parametrize("fused", [False, True])
-def test_one_hop_plan_matches_flat_backend_with_ef(rng, mode, fused):
+def test_one_hop_plan_matches_flat_backend_with_ef(rng, aggregate, mode,
+                                                   fused):
     w, name = 4, f"hier1ef_{mode}"
     gs = _grads(rng, w=w)
     register_hop_plan(HopPlan(name, (HopSpec(mode),)))
@@ -144,7 +145,7 @@ def test_one_hop_plan_matches_flat_backend_with_ef(rng, mode, fused):
 
         def run(plan):
             def one(g, e):
-                return fabric.aggregate(g, plan, ef=e, fused=fused)
+                return aggregate(fabric, g, plan, ef=e, fused=fused)
             return jax.vmap(one, axis_name="w")(gs, efs)
 
         flat, flat_ef = run(flat_plan)
@@ -165,7 +166,7 @@ def _hier_2x2_plan():
                                      HopSpec("gbinary")))
 
 
-def test_two_hop_plan_matches_nested_vmap_oracle(rng):
+def test_two_hop_plan_matches_nested_vmap_oracle(rng, aggregate):
     """Hop 0 = fp32 mean over the *inner* axis, hop 1 = gbinary vote
     over the outer axis: exactly sign(sum_outer(sign(mean_inner(g))))."""
     outer, inner = 2, 2
@@ -177,7 +178,7 @@ def test_two_hop_plan_matches_nested_vmap_oracle(rng):
         plan = AdmissionPlan.lowbit_all("hier_test_2x2")
 
         def one(g):
-            return fabric.aggregate({"p": g}, plan, fused=False)[0]["p"]
+            return aggregate(fabric, {"p": g}, plan, fused=False)[0]["p"]
         got = jax.vmap(jax.vmap(one, axis_name="inner"),
                        axis_name="outer")(gs)
         want = jnp.sign(jnp.sign(jnp.mean(gs, axis=1)).sum(axis=0))
@@ -189,7 +190,7 @@ def test_two_hop_plan_matches_nested_vmap_oracle(rng):
 
 
 @pytest.mark.parametrize("error_feedback", [False, True])
-def test_two_hop_fused_matches_per_leaf(rng, error_feedback):
+def test_two_hop_fused_matches_per_leaf(rng, aggregate, error_feedback):
     outer, inner = 2, 2
     w = outer * inner
     gs = jax.tree.map(
@@ -208,8 +209,9 @@ def test_two_hop_fused_matches_per_leaf(rng, error_feedback):
 
         def run(fused):
             def one(g, e):
-                return fabric.aggregate(
-                    g, plan, ef=(e if error_feedback else None), fused=fused)
+                return aggregate(fabric, g, plan,
+                                 ef=(e if error_feedback else None),
+                                 fused=fused)
             return jax.vmap(jax.vmap(one, axis_name="inner"),
                             axis_name="outer")(gs, efs)
 
@@ -229,7 +231,7 @@ def test_multi_hop_plan_requires_matching_axes(rng):
     fabric = Fabric(dp_axes=("w",), num_workers=w)
     plan = AdmissionPlan.lowbit_all("hier_fp32_gbinary")
     with pytest.raises(ValueError):
-        jax.vmap(lambda g: fabric.aggregate(g, plan, fused=True)[0],
+        jax.vmap(lambda g: fabric.aggregate(g, plan)[0],
                  axis_name="w")(gs)
 
 
@@ -239,10 +241,8 @@ def test_host_local_hier_matches_flat_backbone(rng):
     grads = _grads(rng)
     fabric = Fabric()
     a, _ = fabric.aggregate(grads,
-                            AdmissionPlan.lowbit_all("hier_fp32_gbinary"),
-                            fused=True)
-    b, _ = fabric.aggregate(grads, AdmissionPlan.lowbit_all("gbinary"),
-                            fused=True)
+                            AdmissionPlan.lowbit_all("hier_fp32_gbinary"))
+    b, _ = fabric.aggregate(grads, AdmissionPlan.lowbit_all("gbinary"))
     assert _tree_equal(a, b)
 
 

@@ -20,7 +20,7 @@ hypothesis = pytest.importorskip(
 from hypothesis import given, settings, strategies as st
 
 from repro import kernels as K
-from repro.kernels import ref
+from repro.kernels import fused, ref
 from repro.core import (AdmissionPlan, AggregationMode, CusumGuard,
                         GroupPolicy, bits_per_element, plan_traffic_ratio)
 
@@ -47,8 +47,8 @@ def test_packed_majority_equals_dense(w, seed):
     grads = rng.randn(w, n).astype(np.float32)
     stack = jnp.stack([K.pack_signs(ref.to_plane(jnp.asarray(g)))
                        for g in grads])
-    counts = K.popcount_stack(stack)
-    sw, mw = K.majority_decode(counts, num_workers=w)
+    gate = fused.local_gate_words(stack.shape[1], ternary=False)
+    sw, mw = fused.vote_combine(stack, gate, num_workers=w)
     u = ref.from_plane(K.unpack_ternary(sw, mw), n)
     want = ref.gbinary_aggregate_dense(jnp.asarray(grads))
     np.testing.assert_array_equal(np.asarray(u), np.asarray(want))

@@ -75,15 +75,13 @@ def _cases():
     out = []
     for dt in ("float32", "bfloat16"):
         out += [("pack_signs", dt, 0), ("encode_pack_ef", dt, 0),
-                ("apply_sign_update", dt, 0), ("ef_residual_plane", dt, 0),
+                ("ef_residual_plane", dt, 0),
                 ("threshold_mask_plane", dt, 0), ("vote_pipeline", dt, 1),
                 ("vote_pipeline", dt, 4)]
     out += [("unpack_ternary", "float32", 0),
             ("int4_quant_plane", "float32", 0)]
     for w in (1, 4):
-        out += [("popcount_stack", "int32", w),
-                ("majority_decode", "int32", w),
-                ("vote_combine", "uint32", w)]
+        out += [("vote_combine", "uint32", w)]
     return out
 
 
@@ -104,10 +102,6 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, dtype, w, size):
                        plane),
         "encode_pack_ef": (functools.partial(fused.encode_pack_ef,
                                              interpret=False), plane, plane),
-        "apply_sign_update": (
-            lambda p, sw, mw: K.apply_sign_update(p, sw, mw, 0.01,
-                                                  interpret=False),
-            plane, words, words),
         "ef_residual_plane": (functools.partial(fused.ef_residual_plane,
                                                 interpret=False),
                               plane, scalar),
@@ -120,13 +114,6 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, dtype, w, size):
                                              interpret=False), words, words),
         "int4_quant_plane": (functools.partial(fused.int4_quant_plane,
                                                interpret=False), plane),
-        "popcount_stack": (functools.partial(K.popcount_stack,
-                                             interpret=False),
-                           s((w, r, K.LANE), jnp.uint32)),
-        "majority_decode": (
-            lambda c, g: K.majority_decode(c, num_workers=w, gate_words=g,
-                                           interpret=False),
-            plane, words),
         "vote_combine": (functools.partial(fused.vote_combine,
                                            num_workers=w, interpret=False),
                          s((w, r, K.LANE)), words),

@@ -103,11 +103,10 @@ def test_train_step_scopes_in_compiled_hlo():
                    for n in agg), stage
 
 
-KERNELS = ("fabric_sign_pack", "fabric_popcount_stack",
-           "fabric_majority_decode", "fabric_vote_pipeline",
+KERNELS = ("fabric_sign_pack", "fabric_vote_pipeline",
            "fabric_vote_combine", "fabric_encode_pack_ef",
            "fabric_ef_residual", "fabric_int4_quant", "fabric_threshold_mask",
-           "fabric_unpack_ternary", "fabric_apply_sign_update")
+           "fabric_unpack_ternary")
 
 
 def _kernel_calls():
@@ -115,17 +114,10 @@ def _kernel_calls():
     m, r, w = 8 * K.PACK, 8, 2
     plane = jnp.ones((m, K.LANE), jnp.float32)
     words = jnp.ones((r, K.LANE), jnp.uint32)
-    counts = jnp.ones((m, K.LANE), jnp.int32)
     kw = {"interpret": True}
     return {
         "fabric_sign_pack": lambda: K.pack_signs(plane, **kw),
-        "fabric_popcount_stack": lambda: K.popcount_stack(
-            jnp.ones((w, r, K.LANE), jnp.uint32), **kw),
-        "fabric_majority_decode": lambda: K.majority_decode(
-            counts, num_workers=w, gate_words=words, **kw),
         "fabric_unpack_ternary": lambda: K.unpack_ternary(words, words, **kw),
-        "fabric_apply_sign_update": lambda: K.apply_sign_update(
-            plane, words, words, 0.01, **kw),
         "fabric_encode_pack_ef": lambda: fused.encode_pack_ef(
             plane, plane, **kw),
         "fabric_vote_combine": lambda: fused.vote_combine(
